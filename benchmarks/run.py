@@ -7,6 +7,7 @@ import sys
 import traceback
 
 from benchmarks._util import print_rows
+from repro.common.compile_cache import setup_compile_cache
 
 BENCHES = (
     ("table1_stability", "benchmarks.bench_stability"),
@@ -45,6 +46,7 @@ def check_scenarios(mod) -> list:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", help="substring filter on bench name")
     ap.add_argument("--quick", action="store_true")

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import transfer
+from repro.common.compile_cache import setup_compile_cache
 from repro.common.config import FFMConfig
 from repro.common.metrics import roc_auc
 from repro.core import deepffm
@@ -18,6 +19,7 @@ from repro.data.prefetch import Prefetcher
 from repro.data.synthetic import CTRStream
 from repro.serving.engine import InferenceEngine
 
+setup_compile_cache()
 cfg = FFMConfig(n_fields=12, context_fields=8, hash_space=2**14, k=4,
                 mlp_hidden=(16, 8))
 stream = CTRStream(cfg, seed=7)

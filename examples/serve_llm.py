@@ -15,11 +15,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import transfer
+from repro.common.compile_cache import setup_compile_cache
 from repro.models import registry
 from repro.train.steps import make_serve_step
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=registry.ARCH_IDS)
     ap.add_argument("--batch", type=int, default=4)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import store, transfer
+from repro.common.compile_cache import setup_compile_cache
 from repro.common.config import FFMConfig
 from repro.common.metrics import roc_auc
 from repro.core import deepffm
@@ -24,6 +25,7 @@ from repro.train.hogwild import HogwildTrainer
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=512)

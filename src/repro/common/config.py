@@ -163,3 +163,10 @@ class FFMConfig:
 
     def replace(self, **kw) -> "FFMConfig":
         return dataclasses.replace(self, **kw)
+
+
+# The production deployment's widths: a 2^22-row hash table of 24 fields
+# (16 of them request context), k=8 — 806M FFM weights, 3.2 GB in f32 and
+# 0.8 GB as int8 rows. The chip smoke and the TPU compile tests run at it.
+PROD_FFM = FFMConfig(n_fields=24, context_fields=16, hash_space=2**22, k=8,
+                     mlp_hidden=(64, 32))

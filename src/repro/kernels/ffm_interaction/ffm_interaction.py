@@ -9,32 +9,46 @@ Per example b the kernel computes the full field x field dot matrix
 in one vectorized pass (the DiagMask upper-triangle extraction is a cheap
 static gather done outside — Pallas TPU prefers dense regular access).
 
-Block layout: grid over batch tiles; each step loads (Bt, F, F, K) embeddings
-(+ (Bt, F) values) into VMEM. For the production config (F=24, K=8, Bt=64)
-that is 64*24*24*8*4 B = 1.2 MiB — comfortably inside the ~16 MiB VMEM
-budget, and the trailing K axis is contiguous for clean vector loads.
+Block layouts follow the Mosaic rule that a block's last two dims either
+divide by the (8, 128) tile or equal the array's own dims. Every operand
+here keeps small trailing dims (K, a field count, or 1), so the wrappers
+reshape per-row vectors into ``(..., F, 1)`` column and ``(..., 1, F)`` row
+forms before the call: each block then spans its array's full trailing
+dims, and the kernels only broadcast — they never move a value between the
+lane and sublane axes. The request-row axis of the candidate kernels is a
+squeezed block dim, and per-candidate scalars ride as ``(..., 1, 1)``
+blocks, so any row and candidate count compiles.
+
+The minor axis of every block is ``K`` (8 at production widths), which
+pads to 128 lanes in VMEM: a ``(24, 24, 8)`` f32 example block occupies
+288 KiB there, not 18 KiB. Tile sizes are chosen for that padded size.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import interpret_mode
+
+_SQ = pl.Squeezed()  # block dim of size 1, dropped from the kernel's view
 
 
-def _ffm_kernel(e_ref, v_ref, out_ref):
+def _ffm_kernel(e_ref, vcol_ref, vrow_ref, out_ref):
     e = e_ref[...]  # (Bt, F, F, K)
-    v = v_ref[...]  # (Bt, F)
     et = jnp.swapaxes(e, 1, 2)  # E[b, j, i, k]
     dots = jnp.sum(e * et, axis=-1)  # (Bt, F, F)
-    vv = v[:, :, None] * v[:, None, :]
-    out_ref[...] = dots * vv
+    out_ref[...] = dots * (vcol_ref[...] * vrow_ref[...])
 
 
-def ffm_interaction_matrix(e: jnp.ndarray, v: jnp.ndarray, *, block_b: int = 64,
-                           interpret: bool = True) -> jnp.ndarray:
-    """e: (B, F, F, K) gathered embeddings; v: (B, F) -> (B, F, F) dot matrix."""
+def ffm_interaction_matrix(e: jnp.ndarray, v: jnp.ndarray, *, block_b: int = 8,
+                           interpret=None) -> jnp.ndarray:
+    """e: (B, F, F, K) gathered embeddings; v: (B, F) -> (B, F, F) dot matrix.
+
+    ``block_b`` examples per grid step: at F=24, K=8 one f32 example block
+    pads to 288 KiB of VMEM, so 8 of them (double-buffered, plus the
+    transposed copy and the product) stay inside the 16 MiB scoped limit."""
     b, f, _, k = e.shape
     bt = min(block_b, b)
     pad = (-b) % bt
@@ -42,39 +56,96 @@ def ffm_interaction_matrix(e: jnp.ndarray, v: jnp.ndarray, *, block_b: int = 64,
         e = jnp.pad(e, ((0, pad), (0, 0), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, pad), (0, 0)))
     bp = e.shape[0]
-    grid = (bp // bt,)
     out = pl.pallas_call(
         _ffm_kernel,
-        grid=grid,
+        grid=(bp // bt,),
         in_specs=[
             pl.BlockSpec((bt, f, f, k), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((bt, f), lambda i: (i, 0)),
+            pl.BlockSpec((bt, f, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((bt, 1, f), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bt, f, f), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, f, f), e.dtype),
-        interpret=interpret,
-    )(e, v)
+        interpret=interpret_mode(interpret),
+    )(e, v[:, :, None], v[:, None, :])
     return out[:b]
 
 
-def _cand_kernel(ectx_ref, vctx_ref, ecx_ref, ecc_ref, vcand_ref, xc_ref, aa_ref):
-    ectx = ectx_ref[0]   # (Fc, Fcand, K) — cached ctx embeddings in cand fields
-    vctx = vctx_ref[0]   # (Fc,)
-    ecx = ecx_ref[0]     # (Nt, Fcand, Fc, K) — cand embeddings in ctx fields
-    ecc = ecc_ref[0]     # (Nt, Fcand, Fcand, K) — cand embeddings in cand fields
-    vc = vcand_ref[0]    # (Nt, Fcand)
+def _cand_pairs(ectx, vctx, ecx, ecc, vc_col, vc_row, xc_ref, aa_ref):
+    """Shared body of the candidate-matrix kernels.
+
+    ectx (Fc, Fcand, K) cached ctx embeddings in cand fields; vctx (Fc, 1);
+    ecx (Nt, Fcand, Fc, K) cand embeddings in ctx fields; ecc (Nt, Fcand,
+    Fcand, K) cand embeddings in cand fields; vc_col/vc_row (Nt, Fcand, 1) /
+    (Nt, 1, Fcand) candidate values."""
     # ctx-cand: D[n, i, jc] = <ectx[i, jc], ecx[n, jc, i]> * vctx[i] * vc[n, jc]
     ecx_t = jnp.swapaxes(ecx, 1, 2)  # (Nt, Fc, Fcand, K)
     dots_xc = jnp.sum(ectx[None] * ecx_t, axis=-1)  # (Nt, Fc, Fcand)
-    xc_ref[0] = dots_xc * vctx[None, :, None] * vc[:, None, :]
+    xc_ref[...] = dots_xc * vctx[None] * vc_row
     # cand-cand: D[n, ic, jc] = <ecc[n, ic, jc], ecc[n, jc, ic]> * vc[n,ic] * vc[n,jc]
     dots_aa = jnp.sum(ecc * jnp.swapaxes(ecc, 1, 2), axis=-1)  # (Nt, Fcand, Fcand)
-    aa_ref[0] = dots_aa * vc[:, :, None] * vc[:, None, :]
+    aa_ref[...] = dots_aa * vc_col * vc_row
+
+
+def _cand_kernel(ectx_ref, vctx_ref, ecx_ref, ecc_ref, vcol_ref, vrow_ref,
+                 xc_ref, aa_ref):
+    _cand_pairs(ectx_ref[...], vctx_ref[...], ecx_ref[...], ecc_ref[...],
+                vcol_ref[...], vrow_ref[...], xc_ref, aa_ref)
+
+
+def _cand_call(kernel, ectx, vctx, cand_blocks, vcand, block_n, interpret):
+    """pallas_call plumbing for the candidate-matrix kernels: grid over
+    (request row, candidate tile). ``cand_blocks`` are (R, N, ...) arrays
+    tiled along N; per step one row's cached context block stays resident.
+    Returns xc (R, N, Fc, Fcand), aa (R, N, Fcand, Fcand) f32."""
+    r, fc, fcand, k = ectx.shape
+    n = vcand.shape[1]
+    nt = min(block_n, n)
+    pad = (-n) % nt
+    if pad:
+        vcand = jnp.pad(vcand, ((0, 0), (0, pad), (0, 0)))
+        cand_blocks = [_pad_cands(b, pad) for b in cand_blocks]
+    np_ = vcand.shape[1]
+    cand_blocks = list(cand_blocks) + [vcand[..., :, None],
+                                       vcand[..., None, :]]
+    xc, aa = pl.pallas_call(
+        kernel,
+        grid=(r, np_ // nt),
+        in_specs=[
+            pl.BlockSpec((_SQ, fc, fcand, k), lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((_SQ, fc, 1), lambda i, j: (i, 0, 0)),
+            *[_cand_spec(b, nt) for b in cand_blocks],
+        ],
+        out_specs=[
+            pl.BlockSpec((_SQ, nt, fc, fcand), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((_SQ, nt, fcand, fcand), lambda i, j: (i, j, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((r, np_, fc, fcand), jnp.float32),
+            jax.ShapeDtypeStruct((r, np_, fcand, fcand), jnp.float32),
+        ],
+        interpret=interpret_mode(interpret),
+    )(ectx, vctx[:, :, None], *cand_blocks)
+    return xc[:, :n], aa[:, :n]
+
+
+def _pad_cands(b, pad):
+    return jnp.pad(b, ((0, 0), (0, pad)) + ((0, 0),) * (b.ndim - 2))
+
+
+def _cand_spec(b, nt):
+    """Block of a (R, N, ...) candidate operand: squeezed row, ``nt``
+    candidates, the operand's full trailing dims. The index map ignores any
+    scalar-prefetch refs the grid passes after the grid indices."""
+    tail = b.ndim - 2
+    return pl.BlockSpec(
+        (_SQ, nt) + tuple(b.shape[2:]),
+        lambda i, j, *_: (i, j) + (0,) * tail)
 
 
 def ffm_candidate_matrices(ectx: jnp.ndarray, vctx: jnp.ndarray, ecx: jnp.ndarray,
                            ecc: jnp.ndarray, vcand: jnp.ndarray, *,
-                           block_n: int = 64, interpret: bool = True):
+                           block_n: int = 32, interpret=None):
     """Candidate-block interactions consuming cached context partials (§5).
 
     The companion of :func:`ffm_interaction_matrix` for the context-cache
@@ -91,178 +162,156 @@ def ffm_candidate_matrices(ectx: jnp.ndarray, vctx: jnp.ndarray, ecx: jnp.ndarra
     vcand: (R, N, Fcand)        candidate values
     ->     xc (R, N, Fc, Fcand), aa (R, N, Fcand, Fcand) dot matrices
     """
-    r, fc, fcand, k = ectx.shape
-    n = ecx.shape[1]
-    nt = min(block_n, n)
-    pad = (-n) % nt
-    if pad:
-        ecx = jnp.pad(ecx, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        ecc = jnp.pad(ecc, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        vcand = jnp.pad(vcand, ((0, 0), (0, pad), (0, 0)))
-    np_ = ecx.shape[1]
-    grid = (r, np_ // nt)
-    xc, aa = pl.pallas_call(
-        _cand_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, fc, fcand, k), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, fc), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, nt, fcand, fc, k), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, nt, fcand, fcand, k), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, nt, fcand), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nt, fc, fcand), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, nt, fcand, fcand), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, np_, fc, fcand), ectx.dtype),
-            jax.ShapeDtypeStruct((r, np_, fcand, fcand), ecc.dtype),
-        ],
-        interpret=interpret,
-    )(ectx, vctx, ecx, ecc, vcand)
-    return xc[:, :n], aa[:, :n]
+    return _cand_call(_cand_kernel, ectx, vctx, [ecx, ecc], vcand,
+                      block_n, interpret)
 
 
-def _ctx_tail_block(ectx, vctx, p):
+def _ctx_tail_block(ectx, vcol, vrow, p):
     """Shared fused-kernel context block: the full (Fc, Fc) ctx-ctx pair
     matrix (dots x value products) plus the *tail* pair sum — every pair
     (i, j) with i < j and j >= p, i.e. exactly the pairs a depth-p cached
     prefix is missing. This is ``ffm.extend_context_prefix``'s tail einsum
     folded into the candidate kernel, so a partial-depth context costs no
-    host pair arithmetic on the scoring path."""
+    host pair arithmetic on the scoring path. Returns the (1, 1) tail."""
     fc = ectx.shape[0]
     ec = ectx[:, :fc]                                  # (Fc, Fc, K)
     d = jnp.sum(ec * jnp.swapaxes(ec, 0, 1), axis=-1)  # (Fc, Fc)
-    d = d * (vctx[:, None] * vctx[None, :])
+    d = d * (vcol * vrow)
     ii = jax.lax.broadcasted_iota(jnp.int32, (fc, fc), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (fc, fc), 1)
-    tail = jnp.sum(jnp.where((ii < jj) & (jj >= p), d, 0.0))
+    tail = jnp.where((ii < jj) & (jj >= p), d, 0.0)
+    tail = jnp.sum(jnp.sum(tail, axis=1, keepdims=True), axis=0, keepdims=True)
     return d, tail
 
 
-def _fused_kernel_q8(ectx_ref, vctx_ref, p_ref, base_ref, qcx_ref, qcc_ref,
-                     s_ref, z_ref, vcand_ref, out_ref, dots_ref):
-    ectx = ectx_ref[0]   # (Fc, F, K) f32 — full-depth ctx embeddings
-    vctx = vctx_ref[0]   # (Fc,)
-    p = p_ref[0, 0]      # scalar int32 — cached prefix depth of this row
-    base = base_ref[0]   # (Nt,) f32 — lr_ctx + lr_cand + bias + cached pairs
-    s = s_ref[0]         # (Nt, Fcand) per-hash-row dequant grids
-    z = z_ref[0]
-    vc = vcand_ref[0]    # (Nt, Fcand)
+def _per_candidate_sum(x):
+    """(Nt, A, B) -> (Nt, 1, 1) sum over the two pair axes (keepdims
+    throughout, so no reduction result changes layout)."""
+    return jnp.sum(jnp.sum(x, axis=2, keepdims=True), axis=1, keepdims=True)
+
+
+def _fused_kernel_q8(depth_ref, ectx_ref, vcol_ref, vrow_ref, base_ref,
+                     qcx_ref, qcc_ref, scol_ref, srow_ref, zcol_ref, zrow_ref,
+                     vccol_ref, vcrow_ref, out_ref, dots_ref):
+    p = depth_ref[pl.program_id(0)]  # cached prefix depth of this row
+    ectx = ectx_ref[...]   # (Fc, F, K) f32 — full-depth ctx embeddings
+    vcol = vcol_ref[...]   # (Fc, 1) ctx values
+    base = base_ref[...]   # (Nt, 1, 1) lr_ctx + lr_cand + bias + cached pairs
+    s_col, s_row = scol_ref[...], srow_ref[...]  # (Nt, Fcand, 1) / (Nt, 1, Fcand)
+    z_col, z_row = zcol_ref[...], zrow_ref[...]  # per-hash-row dequant grids
+    vc_col, vc_row = vccol_ref[...], vcrow_ref[...]
     fc = ectx.shape[0]
     k = ectx.shape[-1]
 
     # ctx-ctx: cached pair sum arrives in `base`; only the tail pairs
     # (j >= p) are computed here, in-kernel
-    d, tail = _ctx_tail_block(ectx, vctx, p)
-    dots_ref[0] = d
+    d, tail = _ctx_tail_block(ectx, vcol, vrow_ref[...], p)
+    dots_ref[...] = d
 
     # ctx-cand: f32 ctx activation x int8 candidate codes. Affine-decomposed
     # per candidate row (e = q*s + z): dot(ex, e) = s * dot(ex, q) +
     # z * sum(ex) — the zero-point never multiplies element-wise
     ex = ectx[:, fc:]                                  # (Fc, Fcand, K)
-    qx = qcx_ref[0].astype(jnp.float32)                # (Nt, Fcand, Fc, K)
+    qx = qcx_ref[...].astype(jnp.float32)              # (Nt, Fcand, Fc, K)
     dq = jnp.sum(ex[None] * jnp.swapaxes(qx, 1, 2), axis=-1)  # (Nt, Fc, Fcand)
     esum = jnp.sum(ex, axis=-1)                        # (Fc, Fcand)
-    xc = (s[:, None, :] * dq + z[:, None, :] * esum[None])
-    xc_sum = jnp.sum(xc * vctx[None, :, None] * vc[:, None, :], axis=(1, 2))
+    xc = s_row * dq + z_row * esum[None]
+    xc_sum = _per_candidate_sum(xc * vcol[None] * vc_row)
 
     # cand-cand: int8 x int8 -> int32 accumulation; dequantization touches
     # only the scalar dot results, never the K-vectors. With e_i = q_i*s_i +
     # z_i (per-row grids): dot(e_i, e_j) = s_i s_j Q_ij + s_i z_j A_ij +
     # s_j z_i A_ji + K z_i z_j, where Q (code dot) and A (code row-sums)
     # are exact int32.
-    q = qcc_ref[0].astype(jnp.int32)                   # (Nt, Fcand, Fcand, K)
-    qd = jnp.sum(q * jnp.swapaxes(q, 1, 2), axis=-1).astype(jnp.float32)
+    q = qcc_ref[...].astype(jnp.int32)                 # (Nt, Fcand, Fcand, K)
+    qt = jnp.swapaxes(q, 1, 2)
+    qd = jnp.sum(q * qt, axis=-1).astype(jnp.float32)
     a = jnp.sum(q, axis=-1).astype(jnp.float32)        # (Nt, Fcand, Fcand)
-    aa = (s[:, :, None] * s[:, None, :] * qd
-          + s[:, :, None] * z[:, None, :] * a
-          + s[:, None, :] * z[:, :, None] * jnp.swapaxes(a, 1, 2)
-          + k * z[:, :, None] * z[:, None, :])
-    fcand = vc.shape[-1]
+    a_t = jnp.sum(qt, axis=-1).astype(jnp.float32)     # A_ji
+    aa = (s_col * s_row * qd
+          + s_col * z_row * a
+          + s_row * z_col * a_t
+          + k * z_col * z_row)
+    fcand = vc_row.shape[-1]
     ic = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 0)
     jc = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 1)
-    aa = jnp.where((ic < jc)[None], aa * vc[:, :, None] * vc[:, None, :], 0.0)
-    aa_sum = jnp.sum(aa, axis=(1, 2))
+    aa = jnp.where((ic < jc)[None], aa * vc_col * vc_row, 0.0)
+    aa_sum = _per_candidate_sum(aa)
 
-    out_ref[0] = base + tail + xc_sum + aa_sum
+    out_ref[...] = base + tail[None] + xc_sum + aa_sum
 
 
-def _fused_kernel_rows(ectx_ref, vctx_ref, p_ref, base_ref, ecx_ref, ecc_ref,
-                       vcand_ref, out_ref, dots_ref):
-    ectx = ectx_ref[0]   # (Fc, F, K)
-    vctx = vctx_ref[0]
-    p = p_ref[0, 0]
-    base = base_ref[0]
-    vc = vcand_ref[0]
+def _fused_kernel_rows(depth_ref, ectx_ref, vcol_ref, vrow_ref, base_ref,
+                       ecx_ref, ecc_ref, vccol_ref, vcrow_ref, out_ref,
+                       dots_ref):
+    p = depth_ref[pl.program_id(0)]
+    ectx = ectx_ref[...]   # (Fc, F, K)
+    vcol = vcol_ref[...]
+    base = base_ref[...]
+    vc_col, vc_row = vccol_ref[...], vcrow_ref[...]
 
-    d, tail = _ctx_tail_block(ectx, vctx, p)
-    dots_ref[0] = d
+    d, tail = _ctx_tail_block(ectx, vcol, vrow_ref[...], p)
+    dots_ref[...] = d
 
     ex = ectx[:, ectx.shape[0]:]                       # (Fc, Fcand, K)
-    ecx = ecx_ref[0]                                   # (Nt, Fcand, Fc, K)
+    ecx = ecx_ref[...]                                 # (Nt, Fcand, Fc, K)
     dx = jnp.sum(ex[None] * jnp.swapaxes(ecx, 1, 2), axis=-1)
-    xc_sum = jnp.sum(dx * vctx[None, :, None] * vc[:, None, :], axis=(1, 2))
+    xc_sum = _per_candidate_sum(dx * vcol[None] * vc_row)
 
-    ecc = ecc_ref[0]                                   # (Nt, Fcand, Fcand, K)
+    ecc = ecc_ref[...]                                 # (Nt, Fcand, Fcand, K)
     da = jnp.sum(ecc * jnp.swapaxes(ecc, 1, 2), axis=-1)
-    fcand = vc.shape[-1]
+    fcand = vc_row.shape[-1]
     ic = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 0)
     jc = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 1)
-    da = jnp.where((ic < jc)[None], da * vc[:, :, None] * vc[:, None, :], 0.0)
-    out_ref[0] = base + tail + xc_sum + jnp.sum(da, axis=(1, 2))
+    da = jnp.where((ic < jc)[None], da * vc_col * vc_row, 0.0)
+    out_ref[...] = base + tail[None] + xc_sum + _per_candidate_sum(da)
 
 
 def _fused_call(kernel, ectx, vctx, depth, base, cand_blocks, vcand,
-                block_n: int, interpret: bool):
+                block_n: int, interpret):
     """Common pallas_call plumbing for the fused-logit kernels: grid over
     (request row, candidate tile); per step one row's whole context block
-    plus one candidate tile is resident. Outputs the (R, N) logits and the
-    per-row (Fc, Fc) ctx pair matrix (each candidate tile recomputes and
-    writes the same ctx block — Fc^2 values, noise next to the tile math —
-    which the engine reads back to insert full-depth prefix states)."""
+    plus one candidate tile is resident. ``depth`` is a scalar-prefetch
+    operand (SMEM), read by row. Outputs the (R, N) logits and the per-row
+    (Fc, Fc) ctx pair matrix (each candidate tile recomputes and writes the
+    same ctx block — Fc^2 values, noise next to the tile math — which the
+    engine reads back to insert full-depth prefix states)."""
     r, fc, f, k = ectx.shape
-    fcand = f - fc
     n = vcand.shape[1]
     nt = min(block_n, n)
     pad = (-n) % nt
     if pad:
         base = jnp.pad(base, ((0, 0), (0, pad)))
         vcand = jnp.pad(vcand, ((0, 0), (0, pad), (0, 0)))
-        cand_blocks = [
-            jnp.pad(b, ((0, 0), (0, pad)) + ((0, 0),) * (b.ndim - 2))
-            for b in cand_blocks]
+        cand_blocks = [_pad_cands(b, pad) for b in cand_blocks]
     np_ = vcand.shape[1]
-    grid = (r, np_ // nt)
-    cand_specs = []
-    for b in cand_blocks:
-        tail_dims = b.ndim - 2
-        cand_specs.append(pl.BlockSpec(
-            (1, nt) + b.shape[2:],
-            (lambda i, j, nd=tail_dims: (i, j) + (0,) * nd)))
-    out, dots = pl.pallas_call(
-        kernel,
-        grid=grid,
+    cand_blocks = ([base[..., None, None]] + list(cand_blocks)
+                   + [vcand[..., :, None], vcand[..., None, :]])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(r, np_ // nt),
         in_specs=[
-            pl.BlockSpec((1, fc, f, k), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, fc), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, nt), lambda i, j: (i, j)),
-            *cand_specs,
-            pl.BlockSpec((1, nt, fcand), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((_SQ, fc, f, k), lambda i, j, _: (i, 0, 0, 0)),
+            pl.BlockSpec((_SQ, fc, 1), lambda i, j, _: (i, 0, 0)),
+            pl.BlockSpec((_SQ, 1, fc), lambda i, j, _: (i, 0, 0)),
+            *[_cand_spec(b, nt) for b in cand_blocks],
         ],
         out_specs=[
-            pl.BlockSpec((1, nt), lambda i, j: (i, j)),
-            pl.BlockSpec((1, fc, fc), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((_SQ, nt, 1, 1), lambda i, j, _: (i, j, 0, 0)),
+            pl.BlockSpec((_SQ, fc, fc), lambda i, j, _: (i, 0, 0)),
         ],
+    )
+    out, dots = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((r, np_), jnp.float32),
+            jax.ShapeDtypeStruct((r, np_, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((r, fc, fc), jnp.float32),
         ],
-        interpret=interpret,
-    )(ectx, vctx, depth.reshape(r, 1), base, *cand_blocks, vcand)
-    return out[:, :n], dots
+        interpret=interpret_mode(interpret),
+    )(depth.astype(jnp.int32), ectx, vctx[:, :, None], vctx[:, None, :],
+      *cand_blocks)
+    return out.reshape(r, np_)[:, :n], dots
 
 
 def ffm_fused_logits_q8(ectx: jnp.ndarray, vctx: jnp.ndarray,
@@ -270,7 +319,7 @@ def ffm_fused_logits_q8(ectx: jnp.ndarray, vctx: jnp.ndarray,
                         qcx: jnp.ndarray, qcc: jnp.ndarray,
                         scale: jnp.ndarray, zero: jnp.ndarray,
                         vcand: jnp.ndarray, *, block_n: int = 64,
-                        interpret: bool = True):
+                        interpret=None):
     """One fused Pallas call per padding bucket: context-tail pairs +
     candidate pair terms + the additive FFM head, int8 pair arithmetic.
 
@@ -300,15 +349,17 @@ def ffm_fused_logits_q8(ectx: jnp.ndarray, vctx: jnp.ndarray,
            value products applied — rows of it are the j-major tail pairs
            the engine inserts into the prefix cache after scoring)
     """
+    grids = [scale[..., :, None], scale[..., None, :],
+             zero[..., :, None], zero[..., None, :]]
     return _fused_call(_fused_kernel_q8, ectx, vctx, depth, base,
-                       [qcx, qcc, scale, zero], vcand, block_n, interpret)
+                       [qcx, qcc] + grids, vcand, block_n, interpret)
 
 
 def ffm_fused_logits_rows(ectx: jnp.ndarray, vctx: jnp.ndarray,
                           depth: jnp.ndarray, base: jnp.ndarray,
                           ecx: jnp.ndarray, ecc: jnp.ndarray,
-                          vcand: jnp.ndarray, *, block_n: int = 64,
-                          interpret: bool = True):
+                          vcand: jnp.ndarray, *, block_n: int = 32,
+                          interpret=None):
     """f32 twin of :func:`ffm_fused_logits_q8` for engines serving f32
     tables above the gather cliff: same single-call fusion (tail pairs +
     candidate pairs + additive head), pre-gathered f32 candidate rows
@@ -319,28 +370,22 @@ def ffm_fused_logits_rows(ectx: jnp.ndarray, vctx: jnp.ndarray,
 
 
 def _cand_kernel_q8(ectx_ref, vctx_ref, qcx_ref, qcc_ref, s_ref, z_ref,
-                    vcand_ref, xc_ref, aa_ref):
-    ectx = ectx_ref[0]   # (Fc, Fcand, K) f32 — cached ctx partial (activation)
-    vctx = vctx_ref[0]   # (Fc,)
-    vc = vcand_ref[0]    # (Nt, Fcand)
-    s = s_ref[0][:, :, None, None]  # (Nt, Fcand, 1, 1) per-hash-row grids
-    z = z_ref[0][:, :, None, None]
+                    vcol_ref, vrow_ref, xc_ref, aa_ref):
+    s = s_ref[...]  # (Nt, Fcand, 1, 1) per-hash-row grids
+    z = z_ref[...]
     # in-register dequantize: the int8 codes are what crossed HBM; the f32
     # rows exist only in this tile's VMEM for the duration of the dot pass
-    ecx = qcx_ref[0].astype(jnp.float32) * s + z  # (Nt, Fcand, Fc, K)
-    ecc = qcc_ref[0].astype(jnp.float32) * s + z  # (Nt, Fcand, Fcand, K)
-    ecx_t = jnp.swapaxes(ecx, 1, 2)               # (Nt, Fc, Fcand, K)
-    dots_xc = jnp.sum(ectx[None] * ecx_t, axis=-1)
-    xc_ref[0] = dots_xc * vctx[None, :, None] * vc[:, None, :]
-    dots_aa = jnp.sum(ecc * jnp.swapaxes(ecc, 1, 2), axis=-1)
-    aa_ref[0] = dots_aa * vc[:, :, None] * vc[:, None, :]
+    ecx = qcx_ref[...].astype(jnp.float32) * s + z  # (Nt, Fcand, Fc, K)
+    ecc = qcc_ref[...].astype(jnp.float32) * s + z  # (Nt, Fcand, Fcand, K)
+    _cand_pairs(ectx_ref[...], vctx_ref[...], ecx, ecc, vcol_ref[...],
+                vrow_ref[...], xc_ref, aa_ref)
 
 
 def ffm_candidate_matrices_q8(ectx: jnp.ndarray, vctx: jnp.ndarray,
                               qcx: jnp.ndarray, qcc: jnp.ndarray,
                               scale: jnp.ndarray, zero: jnp.ndarray,
                               vcand: jnp.ndarray, *, block_n: int = 64,
-                              interpret: bool = True):
+                              interpret=None):
     """Fused dequantize + candidate-block interactions (§5 hot loop x §6).
 
     The int8 twin of :func:`ffm_candidate_matrices`: candidate embeddings
@@ -361,38 +406,6 @@ def ffm_candidate_matrices_q8(ectx: jnp.ndarray, vctx: jnp.ndarray,
     vcand: (R, N, Fcand)           candidate values
     ->     xc (R, N, Fc, Fcand), aa (R, N, Fcand, Fcand) f32 dot matrices
     """
-    r, fc, fcand, k = ectx.shape
-    n = qcx.shape[1]
-    nt = min(block_n, n)
-    pad = (-n) % nt
-    if pad:
-        qcx = jnp.pad(qcx, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        qcc = jnp.pad(qcc, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        scale = jnp.pad(scale, ((0, 0), (0, pad), (0, 0)))
-        zero = jnp.pad(zero, ((0, 0), (0, pad), (0, 0)))
-        vcand = jnp.pad(vcand, ((0, 0), (0, pad), (0, 0)))
-    np_ = qcx.shape[1]
-    grid = (r, np_ // nt)
-    xc, aa = pl.pallas_call(
-        _cand_kernel_q8,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, fc, fcand, k), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, fc), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, nt, fcand, fc, k), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, nt, fcand, fcand, k), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, nt, fcand), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, nt, fcand), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, nt, fcand), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nt, fc, fcand), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, nt, fcand, fcand), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, np_, fc, fcand), jnp.float32),
-            jax.ShapeDtypeStruct((r, np_, fcand, fcand), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ectx, vctx, qcx, qcc, scale, zero, vcand)
-    return xc[:, :n], aa[:, :n]
+    return _cand_call(_cand_kernel_q8, ectx, vctx,
+                      [qcx, qcc, scale[..., None, None], zero[..., None, None]],
+                      vcand, block_n, interpret)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -91,7 +93,7 @@ def flash_attention_pallas(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret=None,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     Sk, Kv = k.shape[1], k.shape[2]
@@ -129,7 +131,7 @@ def flash_attention_pallas(
             pltpu.VMEM((cq,), jnp.float32),   # l: running sum
             pltpu.VMEM((cq, D), jnp.float32), # acc: output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     out = out.reshape(B, H, Sq + pq, D)[:, :, :Sq].transpose(0, 2, 1, 3)
     return out

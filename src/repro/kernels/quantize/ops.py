@@ -13,7 +13,7 @@ from repro.core.quantization import QuantMeta, _ceil_dec, _floor_dec, B_MAX
 from repro.kernels.quantize.quantize import dequantize_pallas, minmax, quantize_pallas
 
 
-def quantize(w: jnp.ndarray, alpha: int = 2, beta: int = 2, *, interpret: bool = True):
+def quantize(w: jnp.ndarray, alpha: int = 2, beta: int = 2, *, interpret=None):
     flat = w.reshape(-1).astype(jnp.float32)
     mn, mx = minmax(flat, interpret=interpret)
     w_min = _floor_dec(float(mn), beta)
@@ -25,7 +25,7 @@ def quantize(w: jnp.ndarray, alpha: int = 2, beta: int = 2, *, interpret: bool =
     return q, QuantMeta(w_min, bucket, int(flat.size))
 
 
-def dequantize(q: jnp.ndarray, meta: QuantMeta, *, interpret: bool = True) -> jnp.ndarray:
+def dequantize(q: jnp.ndarray, meta: QuantMeta, *, interpret=None) -> jnp.ndarray:
     return dequantize_pallas(
         q, jnp.float32(meta.w_min), jnp.float32(meta.bucket_size), interpret=interpret
     )

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 B_MAX = 2**16
 LANE = 128
 
@@ -54,7 +56,7 @@ def _pad_lane(x: jnp.ndarray, value: float) -> jnp.ndarray:
     return x
 
 
-def minmax(w: jnp.ndarray, *, block: int = 64 * LANE, interpret: bool = True):
+def minmax(w: jnp.ndarray, *, block: int = 64 * LANE, interpret=None):
     """Blocked min/max reduction over a flat f32 array."""
     n = w.shape[0]
     wp = _pad_lane(w, w[0])
@@ -75,13 +77,13 @@ def minmax(w: jnp.ndarray, *, block: int = 64 * LANE, interpret: bool = True):
             jax.ShapeDtypeStruct((1,), jnp.float32),
             jax.ShapeDtypeStruct((1,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(wp)
     return mn[0], mx[0]
 
 
 def quantize_pallas(w: jnp.ndarray, w_min: jnp.ndarray, bucket: jnp.ndarray,
-                    *, block: int = 64 * LANE, interpret: bool = True) -> jnp.ndarray:
+                    *, block: int = 64 * LANE, interpret=None) -> jnp.ndarray:
     """Flat f32 -> int32 codes in [0, 65535] (uint16 payload semantics)."""
     n = w.shape[0]
     wp = _pad_lane(w, 0.0)
@@ -98,13 +100,13 @@ def quantize_pallas(w: jnp.ndarray, w_min: jnp.ndarray, bucket: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((wp.shape[0],), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(wp, scalars)
     return q[:n]
 
 
 def dequantize_pallas(q: jnp.ndarray, w_min: jnp.ndarray, bucket: jnp.ndarray,
-                      *, block: int = 64 * LANE, interpret: bool = True) -> jnp.ndarray:
+                      *, block: int = 64 * LANE, interpret=None) -> jnp.ndarray:
     n = q.shape[0]
     qp = _pad_lane(q, 0)
     block = min(block, qp.shape[0])
@@ -120,6 +122,6 @@ def dequantize_pallas(q: jnp.ndarray, w_min: jnp.ndarray, bucket: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0],), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qp, scalars)
     return w[:n]

@@ -10,11 +10,11 @@ strategy survives every regime:
   host gather stays flat). Still the in-trace reference everywhere a
   better strategy cannot apply.
 * **Pallas gather-and-dequant** (:mod:`.row_gather`) — on TPU the indices
-  become a scalar-prefetch operand and each grid step DMAs its row
-  directly, so the generic-gather HLO never exists. Selected in-trace on
-  the TPU backend above the cliff (scalar-prefetch grid specs are
-  TPU-only; GPU keeps the generic take, whose gather does not share the
-  XLA-CPU cliff).
+  become a scalar-prefetch operand and each grid step DMAs the block that
+  holds its row, so the generic-gather HLO never exists. The TPU's only
+  in-trace strategy, at every table size: the choice rests on the platform,
+  never on a timing (GPU keeps the generic take, whose gather does not
+  share the XLA-CPU cliff).
 * **Host packed gather** (:func:`gather_codes_np` / :func:`gather_dequant_np`)
   — numpy ``take`` over the widest word view the row byte-length allows
   (int8 rows of 8k bytes move as u64 lanes). Immune to the XLA cliff and
@@ -32,7 +32,6 @@ from __future__ import annotations
 import os
 import threading
 import time
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -97,20 +96,18 @@ def _timed(fn) -> float:
 
 
 def cliff_rows() -> int:
-    """The effective gather-cliff threshold: the per-process calibrated
-    crossover, or the :data:`CLIFF_ROWS` constant when probing is disabled
-    (``REPRO_CLIFF_CALIBRATE=0``) or the probe fails."""
+    """The effective XLA-CPU gather-cliff threshold: the per-process
+    calibrated crossover, or the :data:`CLIFF_ROWS` constant when probing is
+    disabled (``REPRO_CLIFF_CALIBRATE=0``). A probe that fails raises (and
+    is retried by the next call) rather than hiding behind the constant.
+    Only CPU-backend decisions consult it."""
     if os.environ.get("REPRO_CLIFF_CALIBRATE", "1").lower() in ("0", "false"):
         return CLIFF_ROWS
     global _calibrated
     if _calibrated is None:  # double-checked: reads stay lock-free once set
         with _calibrate_lock:
             if _calibrated is None:
-                try:
-                    _calibrated = calibrate_cliff_rows()
-                except Exception:
-                    # never let a probe failure break engine startup
-                    _calibrated = CLIFF_ROWS
+                _calibrated = calibrate_cliff_rows()
     return _calibrated
 
 
@@ -119,8 +116,9 @@ def use_host_gather(n_rows: int) -> bool:
     (numpy) instead of gathering inside the jitted forward: CPU backend (the
     Pallas kernel's scalar-prefetch DMA path needs real accelerator hardware;
     in interpret mode it degenerates to a scan of dynamic slices) and a table
-    past the gather cliff (calibrated per process — :func:`cliff_rows`)."""
-    return n_rows >= cliff_rows() and jax.default_backend() == "cpu"
+    past the gather cliff (calibrated per process — :func:`cliff_rows`).
+    The platform is checked first, so no other platform runs the probe."""
+    return jax.default_backend() == "cpu" and n_rows >= cliff_rows()
 
 
 def _packed_view(flat: np.ndarray):
@@ -203,22 +201,19 @@ def _is_concrete(x) -> bool:
 def gather_dequant_rows(qtable, idx):
     """Strategy-selected gather+dequant from an int8 row-quantized table.
 
-    In-trace: the Pallas kernel on accelerator backends above the cliff,
-    ``jnp.take`` otherwise. Out-of-trace (eager host arrays, e.g. the
-    ``score_uncached`` oracle path): the host packed gather above the cliff.
+    TPU: the Pallas kernel, always. CPU: the host packed gather for eager
+    host arrays above the cliff (e.g. the ``score_uncached`` oracle path),
+    ``jnp.take`` otherwise. Other platforms: ``jnp.take`` (scalar-prefetch
+    grid specs are TPU-only; a GPU gather does not share the XLA-CPU cliff).
     """
     codes = qtable["codes"]
-    n_rows = codes.shape[0]
-    if n_rows >= cliff_rows():
-        if (_is_concrete(codes) and _is_concrete(idx)
-                and jax.default_backend() == "cpu"):
-            return jnp.asarray(gather_dequant_np(qtable, np.asarray(idx)))
-        if jax.default_backend() == "tpu":
-            # scalar-prefetch grid specs are TPU-only; GPU falls through to
-            # the generic take (its gather doesn't share the XLA-CPU cliff)
-            return gather_dequant_rows_q8(codes, qtable["scale"],
-                                          qtable["zero"], idx,
-                                          interpret=False)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return gather_dequant_rows_q8(codes, qtable["scale"], qtable["zero"],
+                                      idx)
+    if (platform == "cpu" and _is_concrete(codes) and _is_concrete(idx)
+            and codes.shape[0] >= cliff_rows()):
+        return jnp.asarray(gather_dequant_np(qtable, np.asarray(idx)))
     extra = (1,) * (codes.ndim - 1)
     c = jnp.take(codes, idx, axis=0).astype(jnp.float32)
     s = jnp.take(qtable["scale"], idx).reshape(idx.shape + extra)
@@ -226,7 +221,3 @@ def gather_dequant_rows(qtable, idx):
     return c * s + z
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def gather_dequant_rows_q8_jit(codes, scale, zero, idx, interpret: bool = True):
-    """Jitted wrapper over the Pallas kernel (bench/test entry point)."""
-    return gather_dequant_rows_q8(codes, scale, zero, idx, interpret=interpret)

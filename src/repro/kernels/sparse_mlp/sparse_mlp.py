@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 
 def _sparse_dw_kernel(x_ref, g_ref, out_ref):
     k = pl.program_id(2)
@@ -39,7 +41,7 @@ def _sparse_dw_kernel(x_ref, g_ref, out_ref):
 
 def sparse_weight_grad_pallas(x: jnp.ndarray, g_masked: jnp.ndarray, *,
                               block_i: int = 128, block_j: int = 128,
-                              block_b: int = 128, interpret: bool = True
+                              block_b: int = 128, interpret=None
                               ) -> jnp.ndarray:
     """dW = x^T @ g_masked with zero-block skipping. x: (B, I); g: (B, J)."""
     b, i = x.shape
@@ -66,6 +68,6 @@ def sparse_weight_grad_pallas(x: jnp.ndarray, g_masked: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i_, j_, k_: (i_, j_)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[1], gp.shape[1]), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp.astype(jnp.float32), gp.astype(jnp.float32))
     return out[:i, :j]

@@ -18,13 +18,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.common.config import FFMConfig
+from repro.common.config import PROD_FFM, FFMConfig
 from repro.common import counting
 from repro.core import deepffm
 from repro.launch import hlo_analysis, mesh as mesh_lib, roofline
-
-PROD_FFM = FFMConfig(n_fields=24, context_fields=16, hash_space=2**22, k=8,
-                     mlp_hidden=(64, 32))
 
 
 def _param_shardings(cfg: FFMConfig, mesh, specs, *, replicate: bool = False):

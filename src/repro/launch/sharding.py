@@ -54,19 +54,10 @@ def logical_rules(cfg, mesh: Mesh) -> Dict[str, Optional[Tuple[str, ...]]]:
 
 
 def abstract_mesh(axis_sizes: Tuple[int, ...], axis_names: Tuple[str, ...]):
-    """Construct an ``AbstractMesh`` across jax versions.
-
-    The constructor changed signature: jax >= 0.5 takes
-    ``(axis_sizes, axis_names)``, jax 0.4.x takes a single tuple of
-    ``(name, size)`` pairs — passing the new-style arguments to the old
-    constructor dies with ``TypeError: 'int' object is not iterable``.
-    """
+    """An ``AbstractMesh`` of the given axis sizes and names."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def _axis_size(mesh: Mesh, axes: Tuple[str, ...]) -> int:

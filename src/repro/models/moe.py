@@ -44,18 +44,6 @@ def moe_specs(cfg) -> Dict[str, ParamSpec]:
     return sp
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (same compat-shim pattern as
-    ``launch.sharding.abstract_mesh``): ``jax.shard_map`` graduated from
-    ``jax.experimental.shard_map`` only after 0.4.x — on 0.4.37 the
-    top-level attribute raises ``AttributeError`` via the deprecations
-    module, so fall back to the experimental import."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def _expert_ffn(cfg, p, h):
     """h: (E_local, C, d) -> (E_local, C, d) through per-expert FFN."""
     up = jnp.einsum("ecd,edf->ecf", h, p["wi"])
@@ -199,7 +187,7 @@ def moe_expert_parallel(cfg, p, x, rt) -> Tuple[jnp.ndarray, jnp.ndarray]:
         x.reshape(T, d),
         jax.sharding.NamedSharding(mesh, P(token_axes, None)),
     )
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs
     )(xt, p_expert)
     return y.reshape(B, S, d), aux

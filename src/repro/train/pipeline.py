@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Dict, Iterable, List, Optional, Protocol, Tuple
 
 import jax
@@ -100,6 +101,56 @@ def touched_paths(batches: Iterable[Dict[str, Any]], model: str
 
 
 # ---------------------------------------------------------------------------
+# Device row storage of the embedding table
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def device_row_width(cfg: FFMConfig) -> int:
+    """Width of one embedding row in the jit trainer's device table:
+    ``n_fields * k`` rounded up to a multiple of 128 lanes.
+
+    On TPU a ``(V, F, k)`` table (or a ``(V, F*k)`` one whose width is not a
+    lane multiple) is stored with ``V`` as the minor axis, and every row
+    scatter first copies it into a row-major layout with the minor axis
+    padded to 128 lanes — 16 GB per table at production widths (2^22 x 24 x
+    8), beyond one v5e chip for the weights and their AdaGrad accumulator.
+    A ``(V, W)`` table with ``W`` a lane multiple is row-major as stored, so
+    the round step gathers and scatters it in place."""
+    return -(-cfg.n_fields * cfg.k // LANES) * LANES
+
+
+def _get_emb(tree, model: str):
+    return tree["emb"] if model == "mlp" else tree["ffm"]["emb"]
+
+
+def _set_emb(tree, model: str, emb):
+    if model == "mlp":
+        return {**tree, "emb": emb}
+    return {**tree, "ffm": {**tree["ffm"], "emb": emb}}
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def to_device_rows(cfg: FFMConfig, model: str, tree):
+    """``(V, F, k)`` embedding leaf of a params/accumulator tree ->
+    zero-padded ``(V, device_row_width)`` rows."""
+    emb = _get_emb(tree, model)
+    flat = emb.reshape(emb.shape[0], -1)
+    pad = device_row_width(cfg) - flat.shape[1]
+    return _set_emb(tree, model, jnp.pad(flat, ((0, 0), (0, pad))))
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def from_device_rows(cfg: FFMConfig, model: str, tree):
+    """Inverse of :func:`to_device_rows`: the model's ``(V, F, k)`` view."""
+    emb = _get_emb(tree, model)
+    rows = emb[:, :cfg.n_fields * cfg.k]
+    return _set_emb(tree, model,
+                    rows.reshape(emb.shape[0], cfg.n_fields, cfg.k))
+
+
+# ---------------------------------------------------------------------------
 # The shared jitted round step
 # ---------------------------------------------------------------------------
 
@@ -170,17 +221,26 @@ def make_sparse_round_step(cfg: FFMConfig, model: str, opt: Optimizer, *,
     Untouched rows see a zero gradient under the dense rule (acc and params
     both unchanged), so this is *exactly* the dense step restricted to the
     touched rows — equivalence-tested against :func:`make_round_step`.
-    Same signature/returns as :func:`make_round_step`.
+    Same signature/returns as :func:`make_round_step`. The embedding tables
+    of ``params`` and the accumulator may be ``(V, F, k)`` or the
+    :func:`to_device_rows` form ``(V, W)``; the step keeps the form it is
+    given (padding columns get no gradient and stay zero).
     """
     emb_path = emb_leaf_path(model)
+    fk = cfg.n_fields * cfg.k
 
-    def get_emb(params):
-        return params["emb"] if model == "mlp" else params["ffm"]["emb"]
+    def take_rows(table, rows, **kw):
+        """(n, F, k) rows of a (V, F, k) or (V, W) table."""
+        got = jnp.take(table, rows, axis=0, **kw)
+        if table.ndim == 2:
+            got = got[:, :fk]
+        return got.reshape(rows.shape[0], cfg.n_fields, cfg.k)
 
-    def set_emb(params, emb):
-        if model == "mlp":
-            return {**params, "emb": emb}
-        return {**params, "ffm": {**params["ffm"], "emb": emb}}
+    def put_rows(table, rows, new):
+        if table.ndim == 2:
+            new = jnp.pad(new.reshape(new.shape[0], fk),
+                          ((0, 0), (0, table.shape[1] - fk)))
+        return table.at[rows].set(new, mode="drop")
 
     def micro(carry, batch):
         params, opt_state, step = carry
@@ -192,8 +252,8 @@ def make_sparse_round_step(cfg: FFMConfig, model: str, opt: Optimizer, *,
         var = {"lr_rows": jnp.take(params["lr"]["w"], flat).reshape(b, f),
                "dense": {"lr_b": params["lr"]["b"]}}
         if emb_path is not None:
-            var["emb_rows"] = jnp.take(get_emb(params), flat, axis=0
-                                       ).reshape(b, f, cfg.n_fields, cfg.k)
+            var["emb_rows"] = take_rows(_get_emb(params, model), flat
+                                        ).reshape(b, f, cfg.n_fields, cfg.k)
         if model in ("mlp", "deepffm"):
             var["dense"]["mlp"] = params["mlp"]
         if model == "deepffm":
@@ -234,10 +294,10 @@ def make_sparse_round_step(cfg: FFMConfig, model: str, opt: Optimizer, *,
         g_rows = {"lr_w": jax.ops.segment_sum(g["lr_rows"].reshape(-1), inv,
                                               num_segments=b * f)}
         if emb_path is not None:
-            p_rows["emb"] = jnp.take(get_emb(params), rows, axis=0,
-                                     mode="clip")
-            a_rows["emb"] = jnp.take(get_emb(opt_state["acc"]), rows, axis=0,
-                                     mode="clip")
+            p_rows["emb"] = take_rows(_get_emb(params, model), rows,
+                                      mode="clip")
+            a_rows["emb"] = take_rows(_get_emb(opt_state["acc"], model), rows,
+                                      mode="clip")
             g_rows["emb"] = jax.ops.segment_sum(
                 g["emb_rows"].reshape(b * f, cfg.n_fields, cfg.k), inv,
                 num_segments=b * f)
@@ -261,10 +321,10 @@ def make_sparse_round_step(cfg: FFMConfig, model: str, opt: Optimizer, *,
         acc = {**acc, "lr": {**acc["lr"], "w": acc_lr_w}}
         params = _set_dense_subtree(params, model, new_p["dense"])
         if emb_path is not None:
-            params = set_emb(params, get_emb(params).at[rows].set(
-                new_p["rows"]["emb"], mode="drop"))
-            acc = set_emb(acc, get_emb(acc).at[rows].set(
-                new_a["rows"]["emb"], mode="drop"))
+            params = _set_emb(params, model, put_rows(
+                _get_emb(params, model), rows, new_p["rows"]["emb"]))
+            acc = _set_emb(acc, model, put_rows(
+                _get_emb(acc, model), rows, new_a["rows"]["emb"]))
 
         outs = {
             "loss": loss,
@@ -469,10 +529,15 @@ class TrainingPipeline:
     first round or on layout/grid changes.
 
     With ``donate=True`` (default, jit backend) each round donates the
-    previous params/opt-state buffers to XLA: ``self.params``/``self.acc``
-    are replaced in place, and any *externally retained* reference to a
-    prior round's arrays is invalidated (jax raises on use). Hold the fresh
-    attributes, not old snapshots — or pass ``donate=False``.
+    previous params/opt-state buffers to XLA: ``self.opt_state`` is replaced
+    in place, and any *externally retained* reference to a prior round's
+    arrays is invalidated (jax raises on use). Hold the fresh attributes,
+    not old snapshots — or pass ``donate=False``.
+
+    The row-sparse jit backend keeps the embedding table and its
+    accumulator in :func:`to_device_rows` form (``opt_state`` too);
+    :attr:`params` and :attr:`acc` return the model's ``(V, F, k)`` view,
+    which is what the update frames carry.
     """
 
     def __init__(self, cfg: FFMConfig, model: str = "deepffm",
@@ -489,9 +554,14 @@ class TrainingPipeline:
         self.backend_name = backend
         self.prefetch_depth = prefetch_depth
         self.delta_updates = delta_updates
-        self.params = deepffm.init_params(cfg, jax.random.PRNGKey(seed), model)
+        params = deepffm.init_params(cfg, jax.random.PRNGKey(seed), model)
+        self._device_rows = (backend == "jit" and row_sparse
+                             and emb_leaf_path(model) is not None)
+        if self._device_rows:
+            params = to_device_rows(cfg, model, params)
+        self._params = params
         self.opt = make_optimizer("adagrad", lr=lr, power_t=power_t)
-        self.opt_state = self.opt.init(self.params)
+        self.opt_state = self.opt.init(params)
         # ``shard_ranges`` (a fleet topology's contiguous row ranges) flips
         # the update channel to fan-out: run_round emits one frame per shard
         # (transfer.ShardedSender) instead of one full-space frame; the
@@ -521,10 +591,20 @@ class TrainingPipeline:
                 cfg, model, lr=lr, power_t=power_t,
                 workers=local_sgd_workers, sparse_backward=sparse_backward)
 
+    def _model_view(self, tree):
+        if self._device_rows:
+            return from_device_rows(self.cfg, self.model, tree)
+        return tree
+
+    @property
+    def params(self):
+        """The current weights, embedding table as ``(V, F, k)``."""
+        return self._model_view(self._params)
+
     @property
     def acc(self):
         """AdaGrad accumulator (legacy ``OnlineTrainer`` surface)."""
-        return self.opt_state["acc"]
+        return self._model_view(self.opt_state["acc"])
 
     def run_round(self, batches: Iterable[Dict[str, Any]]):
         """One online round; returns the versioned update blob for serving —
@@ -532,17 +612,18 @@ class TrainingPipeline:
         when the pipeline was built with ``shard_ranges``."""
         t0 = time.perf_counter()
         batch_list = list(Prefetcher(batches, depth=self.prefetch_depth))
-        self.params, self.opt_state, m = self.backend.run(
-            self.params, self.opt_state, batch_list)
+        self._params, self.opt_state, m = self.backend.run(
+            self._params, self.opt_state, batch_list)
         touched, n_rows = (touched_paths(batch_list, self.model)
                            if self.delta_updates else (None, 0))
         # report.round and the frame's version stamp are the same number: the
         # serving engine tracks it as weights_version
         version = len(self.reports) + 1
+        params = self.params
         if isinstance(self.sender, transfer.ShardedSender):
             # fan-out channel: one frame per shard, same version stamp on
             # all; run_round returns the List[bytes] in shard order
-            update = self.sender.make_updates(self.params, version=version,
+            update = self.sender.make_updates(params, version=version,
                                               touched=touched or None)
             # a fault-injected sender may drop or mangle a shard's frame on
             # the wire; the round still reports the surviving frames' bytes
@@ -557,7 +638,7 @@ class TrainingPipeline:
                 except transfer.FrameError:
                     kind = "corrupt"
         else:
-            update = self.sender.make_update(self.params, version=version,
+            update = self.sender.make_update(params, version=version,
                                              touched=touched or None)
             update_bytes = len(update)
             kind = _KIND_NAMES[transfer.unframe(update).kind]

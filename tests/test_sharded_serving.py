@@ -387,7 +387,9 @@ def test_cliff_calibration_cached_and_bounded(monkeypatch):
     monkeypatch.setattr(rg_ops, "calibrate_cliff_rows",
                         lambda *a, **k: (_ for _ in ()).throw(RuntimeError()))
     monkeypatch.setattr(rg_ops, "_calibrated", None)
-    assert rg_ops.cliff_rows() == rg_ops.CLIFF_ROWS  # probe failure fallback
+    with pytest.raises(RuntimeError):  # a failed probe surfaces, uncached
+        rg_ops.cliff_rows()
+    assert rg_ops._calibrated is None
 
 
 def test_f32_host_gather_parity(params):

@@ -15,8 +15,9 @@ from repro.data.synthetic import CTRStream
 from repro.optim import make_optimizer
 from repro.serving.engine import InferenceEngine
 from repro.train.loop import OnlineTrainer
-from repro.train.pipeline import (TrainingPipeline, make_round_step,
-                                  make_sparse_round_step, touched_paths)
+from repro.train.pipeline import (TrainingPipeline, from_device_rows,
+                                  make_round_step, make_sparse_round_step,
+                                  to_device_rows, touched_paths)
 
 pytestmark = pytest.mark.tier1
 
@@ -32,11 +33,16 @@ def _stack(batches):
 # Trainer layer
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model", ["linear", "mlp", "ffm", "deepffm"])
-def test_sparse_round_step_matches_dense(model):
+@pytest.mark.parametrize("model,device_rows", [
+    pytest.param(m, rows, id=m + ("-device_rows" if rows else ""))
+    for m, rows in [("linear", False), ("mlp", False), ("ffm", False),
+                    ("deepffm", False), ("mlp", True), ("ffm", True),
+                    ("deepffm", True)]])
+def test_sparse_round_step_matches_dense(model, device_rows):
     """The O(batch) gather/scatter AdaGrad step is the dense full-space step
     restricted to the touched rows — params, acc, and pre-update scores all
-    agree (duplicate feature occurrences included)."""
+    agree (duplicate feature occurrences included), whether its embedding
+    tables are ``(V, F, k)`` or the trainer's lane-padded ``(V, W)`` rows."""
     opt = make_optimizer("adagrad", lr=0.1)
     stream = CTRStream(CFG, seed=1)
     stacked = _stack([stream.sample(32) for _ in range(4)])
@@ -44,9 +50,16 @@ def test_sparse_round_step_matches_dense(model):
     for name, maker in (("dense", make_round_step),
                         ("sparse", make_sparse_round_step)):
         params = deepffm.init_params(CFG, jax.random.PRNGKey(0), model)
+        if device_rows and name == "sparse":
+            params = to_device_rows(CFG, model, params)
         state = opt.init(params)
         rf = maker(CFG, model, opt, donate=False)
-        results[name] = rf(params, state, jnp.zeros((), jnp.int32), stacked)
+        p, st, step, outs = rf(params, state, jnp.zeros((), jnp.int32),
+                               stacked)
+        if device_rows and name == "sparse":
+            p = from_device_rows(CFG, model, p)
+            st = {"acc": from_device_rows(CFG, model, st["acc"])}
+        results[name] = (p, st, step, outs)
     for a, b in zip(jax.tree_util.tree_leaves(results["dense"][:2]),
                     jax.tree_util.tree_leaves(results["sparse"][:2])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
