@@ -190,6 +190,16 @@ def _per_candidate_sum(x):
     return jnp.sum(jnp.sum(x, axis=2, keepdims=True), axis=1, keepdims=True)
 
 
+def _cand_cand_sum(dots, vc_col, vc_row):
+    """(Nt, Fcand, Fcand) cand-cand dots -> (Nt, 1, 1) sum of the pairs
+    ic < jc, each times its two candidate values."""
+    fcand = vc_row.shape[-1]
+    ic = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 0)
+    jc = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 1)
+    return _per_candidate_sum(
+        jnp.where((ic < jc)[None], dots * vc_col * vc_row, 0.0))
+
+
 def _fused_kernel_q8(depth_ref, ectx_ref, vcol_ref, vrow_ref, base_ref,
                      qcx_ref, qcc_ref, scol_ref, srow_ref, zcol_ref, zrow_ref,
                      vccol_ref, vcrow_ref, out_ref, dots_ref):
@@ -232,11 +242,7 @@ def _fused_kernel_q8(depth_ref, ectx_ref, vcol_ref, vrow_ref, base_ref,
           + s_col * z_row * a
           + s_row * z_col * a_t
           + k * z_col * z_row)
-    fcand = vc_row.shape[-1]
-    ic = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 0)
-    jc = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 1)
-    aa = jnp.where((ic < jc)[None], aa * vc_col * vc_row, 0.0)
-    aa_sum = _per_candidate_sum(aa)
+    aa_sum = _cand_cand_sum(aa, vc_col, vc_row)
 
     out_ref[...] = base + tail[None] + xc_sum + aa_sum
 
@@ -260,11 +266,8 @@ def _fused_kernel_rows(depth_ref, ectx_ref, vcol_ref, vrow_ref, base_ref,
 
     ecc = ecc_ref[...]                                 # (Nt, Fcand, Fcand, K)
     da = jnp.sum(ecc * jnp.swapaxes(ecc, 1, 2), axis=-1)
-    fcand = vc_row.shape[-1]
-    ic = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 0)
-    jc = jax.lax.broadcasted_iota(jnp.int32, (fcand, fcand), 1)
-    da = jnp.where((ic < jc)[None], da * vc_col * vc_row, 0.0)
-    out_ref[...] = base + tail[None] + xc_sum + _per_candidate_sum(da)
+    out_ref[...] = base + tail[None] + xc_sum + _cand_cand_sum(da, vc_col,
+                                                               vc_row)
 
 
 def _fused_call(kernel, ectx, vctx, depth, base, cand_blocks, vcand,

@@ -713,8 +713,8 @@ class InferenceEngine:
     * ``host_gather`` — pre-gather candidate rows/LR terms on host (packed
       numpy gather) and score through :func:`batched_candidates_forward_q8`
       (int8 tables) or :func:`batched_candidates_forward_rows` (f32 tables),
-      dodging the XLA-CPU gather cliff — both dtypes hit it; the threshold
-      is probed per process at engine startup
+      dodging the XLA-CPU gather cliff — both dtypes hit it; on the CPU
+      backend the threshold is probed per process at engine startup
       (``row_gather.ops.cliff_rows``, constant fallback via
       ``REPRO_CLIFF_CALIBRATE=0``). ``None`` (default) auto-selects by
       table size and backend (``row_gather.ops.use_host_gather``).
