@@ -21,6 +21,14 @@ fields, 16 of them request context, k=8):
            ``quantization.pair_logit_tolerance`` of the trainer's weights,
            the fused scores within ``fused_logit_tolerance`` of the engine's
            own dequantized tables (the same quantized model).
+  exact    Every engine against the oracle over its own dequantized rows,
+           within ``EXACT_TOL``: all that is left there is f32 rounding in
+           another summation order. The staged engine rescores the same
+           requests at float32 matmul precision for it (its MLP head runs at
+           the platform's default precision, bf16 passes on TPU), and so
+           does the uncached forward. The oracle over the same rows rounded
+           to bfloat16 is the control: it must fall outside the limit, or
+           the check could not see a kernel that computes in bf16.
 
 Each phase prints one line: its seconds (compilation reported as set-up),
 requests scored, the largest deviation next to its tolerance, and the bytes
@@ -29,12 +37,10 @@ to the device on every call). Any failure raises, so the script exits
 non-zero; only when every phase passed does it print, as its last line,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
-    python chip_smoke.py [--rounds 3] [--steps 8] [--batch 512]
-                         [--requests 16] [--candidates 256] [--batches 2]
+    python chip_smoke.py
 """
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import sys
@@ -54,6 +60,17 @@ from repro.core import quantization as Q  # noqa: E402
 from repro.data.synthetic import CTRStream  # noqa: E402
 from repro.serving.engine import InferenceEngine  # noqa: E402
 from repro.train.pipeline import TrainingPipeline  # noqa: E402
+
+
+# Widths are PROD_FFM's, uncut. Training and traffic are cut to what the chip
+# runs in a few minutes: rounds x steps x examples, batches x requests x
+# candidates.
+ROUNDS, STEPS, BATCH = 3, 8, 512
+N_BATCHES, N_REQUESTS, N_CANDIDATES = 2, 16, 256
+
+# Limit of the exact checks (see the module docstring): above the sound
+# readings, below the bf16 control's. Readings in PERF.md.
+EXACT_TOL = 1e-4
 
 
 class SmokeFailure(RuntimeError):
@@ -219,6 +236,27 @@ def compare(name: str, got, want, tol: float, **bound_inputs) -> None:
     check(dev <= tol, f"{name}: deviation {dev} above tolerance {tol}")
 
 
+def bf16_rows(params):
+    """``params`` with its embedding rows rounded to bfloat16."""
+    emb = np.asarray(params["ffm"]["emb"])
+    return {**params, "ffm": {**params["ffm"], "emb": emb.astype(
+        jnp.bfloat16).astype(np.float32)}}
+
+
+def compare_exact(name: str, got, want, control, **readings) -> None:
+    """``got`` within ``EXACT_TOL`` of ``want``, the oracle over the
+    engine's own dequantized rows; ``control``, that oracle over the rows
+    rounded to bf16, must be farther from ``want`` than the limit."""
+    dev = float(np.max(np.abs(np.asarray(got) - want)))
+    ctl = float(np.max(np.abs(control - want)))
+    say(f"exact[{name}]", rows=int(np.size(got)), max_abs_dev=f"{dev:.3e}",
+        limit=f"{EXACT_TOL:.3e}", bf16_control_dev=f"{ctl:.3e}",
+        **{k: f"{v:.3e}" for k, v in readings.items()})
+    check(dev <= EXACT_TOL, f"{name}: deviation {dev} above {EXACT_TOL}")
+    check(ctl > EXACT_TOL, f"{name}: the bf16 control reads {ctl}, inside "
+                           f"the limit {EXACT_TOL}: the check is blind")
+
+
 def value_max(reqs) -> float:
     return float(max(max(np.abs(cv).max(), np.abs(kv).max())
                      for _, cv, _, kv in reqs))
@@ -248,6 +286,28 @@ def run(cfg: FFMConfig, *, rounds: int, steps: int, batch: int,
     want = oracle(cfg, "deepffm", params, idx, val)
     compare("staged", got, want, pair_tol, **pair_bound)
 
+    # ... and vs its own dequantized rows, rescored at float32 precision
+    rt, rt_idx = dequantized_rows(qp, idx)
+    want_q = oracle(cfg, "deepffm", rt, rt_idx, val)
+    control = oracle(cfg, "deepffm", bf16_rows(rt), rt_idx, val)
+    with jax.default_matmul_precision("float32"):
+        got_f32 = np.concatenate([np.concatenate(staged.score_batch(reqs_b))
+                                  for reqs_b in batches])
+    compare_exact("staged", got_f32, want_q, control,
+                  default_precision_dev=float(np.max(np.abs(got - want_q))))
+
+    # the staged engine's uncached forward: Pallas row gather + full
+    # interaction matrix over the int8 table, at float32 precision
+    t0 = time.perf_counter()
+    with jax.default_matmul_precision("float32"):
+        unc = np.concatenate([
+            np.asarray(staged.score_uncached(*r, use_backend=True))
+            for r in reqs[:n_uncached]])
+    say("serve[uncached]", requests=n_uncached,
+        seconds=f"{time.perf_counter() - t0:.3f}(includes compile)")
+    compare("uncached", unc, want[:unc.size], pair_tol, **pair_bound)
+    compare_exact("uncached", unc, want_q[:unc.size], control[:unc.size])
+
     # fused ffm engine vs its own dequantized tables
     fused, got = serve(cfg, "ffm", params, manifest, frames, batches,
                        fused=True, name="fused")
@@ -258,33 +318,17 @@ def run(cfg: FFMConfig, *, rounds: int, steps: int, batch: int,
     fused_bound = dict(emb_absmax=emb_absmax,
                        eps=Q.row_max_error(qp["ffm"]["emb"]), vmax=vmax,
                        lr_max=lr_max)
-    compare("fused", got, oracle(cfg, "ffm", rt, rt_idx, val),
+    want_q = oracle(cfg, "ffm", rt, rt_idx, val)
+    compare("fused", got, want_q,
             Q.fused_logit_tolerance(cfg, **fused_bound), **fused_bound)
-
-    # the staged engine's uncached forward: Pallas row gather + full
-    # interaction matrix over the int8 table
-    t0 = time.perf_counter()
-    unc = [np.asarray(staged.score_uncached(*r, use_backend=True))
-           for r in reqs[:n_uncached]]
-    say("serve[uncached]", requests=len(unc),
-        seconds=f"{time.perf_counter() - t0:.3f}(includes compile)")
-    compare("uncached", np.concatenate(unc),
-            want[:sum(u.size for u in unc)], pair_tol, **pair_bound)
+    compare_exact("fused", got, want_q,
+                  oracle(cfg, "ffm", bf16_rows(rt), rt_idx, val))
     for eng in (staged, fused):
         eng.update_pipe().close()
         eng.close()
 
 
-def main(argv=None) -> None:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--steps", type=int, default=8)
-    ap.add_argument("--batch", type=int, default=512)
-    ap.add_argument("--requests", type=int, default=16)
-    ap.add_argument("--candidates", type=int, default=256)
-    ap.add_argument("--batches", type=int, default=2)
-    args = ap.parse_args(argv)
-
+def main() -> None:
     info = device_info()
     say("device", platform=info["platform"], kind=repr(info["kind"]),
         count=info["count"])
@@ -293,13 +337,11 @@ def main(argv=None) -> None:
     say("config", n_fields=cfg.n_fields, context_fields=cfg.context_fields,
         k=cfg.k, hash_space=cfg.hash_space, mlp_hidden=cfg.mlp_hidden)
     say("cut", widths="none (PROD_FFM)",
-        training=f"{args.rounds} rounds x {args.steps} steps x "
-                 f"{args.batch} examples",
-        traffic=f"{args.batches} batches x {args.requests} requests x "
-                f"{args.candidates} candidates")
-    run(cfg, rounds=args.rounds, steps=args.steps, batch=args.batch,
-        n_requests=args.requests, n_candidates=args.candidates,
-        n_batches=args.batches)
+        training=f"{ROUNDS} rounds x {STEPS} steps x {BATCH} examples",
+        traffic=f"{N_BATCHES} batches x {N_REQUESTS} requests x "
+                f"{N_CANDIDATES} candidates")
+    run(cfg, rounds=ROUNDS, steps=STEPS, batch=BATCH, n_requests=N_REQUESTS,
+        n_candidates=N_CANDIDATES, n_batches=N_BATCHES)
     print(json.dumps({"ok": True, "device": info}), flush=True)
 
 

@@ -109,7 +109,7 @@ LANES = 128
 
 def device_row_width(cfg: FFMConfig) -> int:
     """Width of one embedding row in the jit trainer's device table:
-    ``n_fields * k`` rounded up to a multiple of 128 lanes.
+    ``n_fields * k``, rounded up to a multiple of 128 lanes on TPU.
 
     On TPU a ``(V, F, k)`` table (or a ``(V, F*k)`` one whose width is not a
     lane multiple) is stored with ``V`` as the minor axis, and every row
@@ -117,8 +117,11 @@ def device_row_width(cfg: FFMConfig) -> int:
     padded to 128 lanes — 16 GB per table at production widths (2^22 x 24 x
     8), beyond one v5e chip for the weights and their AdaGrad accumulator.
     A ``(V, W)`` table with ``W`` a lane multiple is row-major as stored, so
-    the round step gathers and scatters it in place."""
-    return -(-cfg.n_fields * cfg.k // LANES) * LANES
+    the round step gathers and scatters it in place. Other platforms store
+    any table row-major: there the rows are ``n_fields * k`` wide, unpadded.
+    """
+    lanes = LANES if jax.default_backend() == "tpu" else 1
+    return -(-cfg.n_fields * cfg.k // lanes) * lanes
 
 
 def _get_emb(tree, model: str):
@@ -221,25 +224,22 @@ def make_sparse_round_step(cfg: FFMConfig, model: str, opt: Optimizer, *,
     Untouched rows see a zero gradient under the dense rule (acc and params
     both unchanged), so this is *exactly* the dense step restricted to the
     touched rows — equivalence-tested against :func:`make_round_step`.
-    Same signature/returns as :func:`make_round_step`. The embedding tables
-    of ``params`` and the accumulator may be ``(V, F, k)`` or the
-    :func:`to_device_rows` form ``(V, W)``; the step keeps the form it is
-    given (padding columns get no gradient and stay zero).
+    Same signature/returns as :func:`make_round_step`, except that the
+    embedding tables of ``params`` and the accumulator are in the
+    :func:`to_device_rows` form ``(V, W)`` (padding columns, if any, get no
+    gradient and stay zero).
     """
     emb_path = emb_leaf_path(model)
     fk = cfg.n_fields * cfg.k
 
     def take_rows(table, rows, **kw):
-        """(n, F, k) rows of a (V, F, k) or (V, W) table."""
-        got = jnp.take(table, rows, axis=0, **kw)
-        if table.ndim == 2:
-            got = got[:, :fk]
+        """(n, F, k) model rows of a (V, W) device-row table."""
+        got = jnp.take(table, rows, axis=0, **kw)[:, :fk]
         return got.reshape(rows.shape[0], cfg.n_fields, cfg.k)
 
     def put_rows(table, rows, new):
-        if table.ndim == 2:
-            new = jnp.pad(new.reshape(new.shape[0], fk),
-                          ((0, 0), (0, table.shape[1] - fk)))
+        new = jnp.pad(new.reshape(new.shape[0], fk),
+                      ((0, 0), (0, table.shape[1] - fk)))
         return table.at[rows].set(new, mode="drop")
 
     def micro(carry, batch):

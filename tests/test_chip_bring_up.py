@@ -82,8 +82,9 @@ def test_tpu_gather_choice_ignores_timing_probe(monkeypatch):
 
 def test_chip_smoke_phases_tiny_on_cpu(capsys):
     """Rehearsal at a tiny configuration: train -> frames -> both engines
-    ingest and score -> oracle parity (the phases raise on a deviation
-    above tolerance)."""
+    ingest and score -> oracle parity, loose and exact (the phases raise on
+    a deviation above tolerance, and when the bf16 control falls inside the
+    exact limit)."""
     smoke = _load_smoke()
     cfg = FFMConfig(n_fields=6, context_fields=4, hash_space=2 ** 10, k=4,
                     mlp_hidden=(8,))
@@ -91,7 +92,8 @@ def test_chip_smoke_phases_tiny_on_cpu(capsys):
               n_candidates=8, n_batches=2)
     out = capsys.readouterr().out
     for phase in ("train", "serve[staged]", "compare[staged]",
-                  "compare[uncached]", "serve[fused]", "compare[fused]"):
+                  "exact[staged]", "compare[uncached]", "exact[uncached]",
+                  "serve[fused]", "compare[fused]", "exact[fused]"):
         assert f"\n{phase}: " in "\n" + out, phase
     assert '"ok"' not in out  # only main() reports success
 

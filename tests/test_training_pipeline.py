@@ -9,13 +9,14 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import layout, transfer
-from repro.common.config import FFMConfig
+from repro.common.config import PROD_FFM, FFMConfig
 from repro.core import deepffm
 from repro.data.synthetic import CTRStream
 from repro.optim import make_optimizer
 from repro.serving.engine import InferenceEngine
 from repro.train.loop import OnlineTrainer
-from repro.train.pipeline import (TrainingPipeline, from_device_rows,
+from repro.train.pipeline import (TrainingPipeline, device_row_width,
+                                  emb_leaf_path, from_device_rows,
                                   make_round_step, make_sparse_round_step,
                                   to_device_rows, touched_paths)
 
@@ -33,32 +34,29 @@ def _stack(batches):
 # Trainer layer
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model,device_rows", [
-    pytest.param(m, rows, id=m + ("-device_rows" if rows else ""))
-    for m, rows in [("linear", False), ("mlp", False), ("ffm", False),
-                    ("deepffm", False), ("mlp", True), ("ffm", True),
-                    ("deepffm", True)]])
-def test_sparse_round_step_matches_dense(model, device_rows):
-    """The O(batch) gather/scatter AdaGrad step is the dense full-space step
-    restricted to the touched rows — params, acc, and pre-update scores all
-    agree (duplicate feature occurrences included), whether its embedding
-    tables are ``(V, F, k)`` or the trainer's lane-padded ``(V, W)`` rows."""
+def _sparse_step_vs_dense(cfg, model):
+    """One round of the dense step on ``(V, F, k)`` tables and of the
+    sparse step on the :func:`to_device_rows` form, from the same start;
+    returns both results, the sparse tables viewed back as ``(V, F, k)``,
+    and the sparse step's own tables."""
+    device_rows = emb_leaf_path(model) is not None
     opt = make_optimizer("adagrad", lr=0.1)
-    stream = CTRStream(CFG, seed=1)
+    stream = CTRStream(cfg, seed=1)
     stacked = _stack([stream.sample(32) for _ in range(4)])
     results = {}
     for name, maker in (("dense", make_round_step),
                         ("sparse", make_sparse_round_step)):
-        params = deepffm.init_params(CFG, jax.random.PRNGKey(0), model)
+        params = deepffm.init_params(cfg, jax.random.PRNGKey(0), model)
         if device_rows and name == "sparse":
-            params = to_device_rows(CFG, model, params)
+            params = to_device_rows(cfg, model, params)
         state = opt.init(params)
-        rf = maker(CFG, model, opt, donate=False)
+        rf = maker(cfg, model, opt, donate=False)
         p, st, step, outs = rf(params, state, jnp.zeros((), jnp.int32),
                                stacked)
+        raw = (p, st)
         if device_rows and name == "sparse":
-            p = from_device_rows(CFG, model, p)
-            st = {"acc": from_device_rows(CFG, model, st["acc"])}
+            p = from_device_rows(cfg, model, p)
+            st = {"acc": from_device_rows(cfg, model, st["acc"])}
         results[name] = (p, st, step, outs)
     for a, b in zip(jax.tree_util.tree_leaves(results["dense"][:2]),
                     jax.tree_util.tree_leaves(results["sparse"][:2])):
@@ -67,6 +65,34 @@ def test_sparse_round_step_matches_dense(model, device_rows):
     np.testing.assert_allclose(np.asarray(results["dense"][3]["scores"]),
                                np.asarray(results["sparse"][3]["scores"]),
                                rtol=1e-4, atol=1e-6)
+    return raw
+
+
+@pytest.mark.parametrize("model", ["linear", "mlp", "ffm", "deepffm"])
+def test_sparse_round_step_matches_dense(model):
+    """The O(batch) gather/scatter AdaGrad step is the dense full-space step
+    restricted to the touched rows — params, acc, and pre-update scores all
+    agree (duplicate feature occurrences included). The sparse step keeps
+    its embedding tables as the trainer's ``(V, W)`` device rows, unpadded
+    off the TPU."""
+    _sparse_step_vs_dense(CFG, model)
+
+
+def test_sparse_round_step_on_lane_padded_rows(monkeypatch):
+    """On TPU the device rows are padded to a multiple of 128 lanes; the
+    sparse step on such rows still equals the dense step, and the padding
+    columns stay zero."""
+    assert device_row_width(PROD_FFM) == PROD_FFM.n_fields * PROD_FFM.k
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a config of its own, so that to_device_rows traces under the TPU width
+    cfg = CFG.replace(hash_space=2 ** 11)
+    fk = cfg.n_fields * cfg.k
+    assert device_row_width(cfg) == 128 > fk
+    assert device_row_width(PROD_FFM) == 256
+    params, state = _sparse_step_vs_dense(cfg, "deepffm")
+    for table in (params["ffm"]["emb"], state["acc"]["ffm"]["emb"]):
+        assert table.shape == (cfg.hash_space, 128)
+        assert not np.asarray(table[:, fk:]).any()
 
 
 def test_sparse_backward_grads_equal_autodiff_on_deepffm():
