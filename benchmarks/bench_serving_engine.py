@@ -48,20 +48,6 @@ Four traffic shapes through one :class:`InferenceEngine` per configuration:
   degradation), the replication-overhead flag (no measurable no-fault
   regression), and freshness across a forced NACK->resync on a corrupted
   delta frame (seconds to byte-exact recovery).
-* ``roofline`` — the serving roofline grounded in the engine's *deployed*
-  forward: per arm (staged q8 vs fused q8) the compiled candidate-forward
-  HLO is lowered at the measured bucket shape and walked for bytes/flops
-  (``launch.hlo_analysis``), the host pre-gather traffic is added
-  (``InferenceEngine.host_gather_bytes``), and bytes/prediction vs the
-  box's measured copy bandwidth gives the preds/s bound the achieved
-  throughput is situated against — now both **per-stream** (one worker vs
-  single-thread copy bandwidth) and **aggregate** (the parallel engine at
-  the auto worker count vs the measured multi-stream bandwidth, which
-  grows sublinearly because concurrent streams share the memory
-  controller). Acceptance: the fused one-Pallas-call path moves fewer
-  bytes/prediction *and* achieves more preds/s than the staged chain,
-  while staying inside ``fused_logit_tolerance`` of the staged oracle and
-  ``pair_logit_tolerance`` of the f32 forward.
 
 Writes ``BENCH_serving.json`` (provenance-stamped via ``write_bench_json``).
 ``benchmarks/run.py --smoke`` checks every name in :data:`SCENARIOS` exists
@@ -92,7 +78,7 @@ CFG = FFMConfig(n_fields=24, context_fields=16, hash_space=2**16, k=8,
 BENCH_FILE = "BENCH_serving.json"
 SCENARIOS = ("results", "overlap_traffic", "quantized_serving",
              "gather_cliff", "sharded_scaling", "parallel_scaling",
-             "roofline", "degraded_serving")
+             "degraded_serving")
 
 
 def _drive(engine: InferenceEngine, reqs, *, uncached: bool = False) -> dict:
@@ -345,20 +331,6 @@ def run(quick: bool = False):
         f"byte_exact={rs['byte_exact']} "
         f"nack={'yes' if rs['nack_error'] else 'no'}"))
 
-    # -- roofline: staged vs fused q8, bytes/prediction vs preds/s bound -----
-    roofline = _roofline_scenario(quick)
-    for name in ("staged_q8", "fused_q8"):
-        r = roofline[name]
-        rf = r["roofline"]
-        agg = rf["aggregate_fraction_of_bound"]
-        rows.append(row(
-            f"serving_engine/roofline_{name}", r["us_per_batch"],
-            f"preds/s={r['predictions_per_s']:.0f} "
-            f"bytes/pred={rf['bytes_per_prediction']:.0f} "
-            f"bound={rf['bound_preds_per_s']:.0f} "
-            f"frac={rf['fraction_of_bound']:.3f} "
-            f"agg_frac={'n/a' if agg is None else f'{agg:.3f}'}"))
-
     write_bench_json(
         BENCH_FILE,
         {"config": {"n_fields": CFG.n_fields,
@@ -373,7 +345,6 @@ def run(quick: bool = False):
          "gather_cliff": cliff,
          "sharded_scaling": sharded,
          "parallel_scaling": parallel,
-         "roofline": roofline,
          "degraded_serving": degraded})
     return rows
 
@@ -904,192 +875,6 @@ def _parallel_scaling_scenario(quick: bool) -> dict:
             # None on a single-core box: the auto policy disables the
             # pipeline there, so a speedup is unobservable by design
             "parallel_speedup_1_5x_on_multicore": speedup_ok,
-        },
-    }
-
-
-def _roofline_scenario(quick: bool) -> dict:
-    """Serving roofline grounded in the engine's deployed forward (§5 x §6).
-
-    Two quantized host-gather arms on identical gather-heavy traffic —
-    ``staged`` (the PR 5 chain: context extend, candidate pair terms, head,
-    each its own jit) vs ``fused`` (one Pallas call per bucket, int8 pair
-    arithmetic) — each measured for preds/s, then situated on the roofline:
-    ``lower_candidates_forward`` at the traffic's (rb, nb) bucket gives the
-    *compiled* per-call HLO bytes/flops, ``host_gather_bytes`` adds the
-    numpy pre-gather traffic the HLO cannot see, and the box's measured
-    copy bandwidth turns bytes/prediction into the preds/s bound. Parity is
-    checked against the staged oracle (``fused_logit_tolerance`` — the only
-    new error is f32 reassociation plus the affine int8 decomposition) and
-    against the direct f32 forward (``pair_logit_tolerance`` envelope).
-
-    Each arm is measured twice: pinned ``parallel=1`` (the per-stream
-    number, against single-thread copy bandwidth) and at the auto worker
-    count (the aggregate number, against the measured multi-stream
-    bandwidth — on a 1-core box both collapse to the same measurement).
-    ``host_gather_bytes`` is tightened with the traffic's actual unique-row
-    count (fresh slates: every padded slot is a unique deduped row here).
-    """
-    from repro.launch import roofline as RL
-
-    v = 2**16 if quick else 2**18
-    cfg = FFMConfig(n_fields=CFG.n_fields, context_fields=CFG.context_fields,
-                    hash_space=v, k=CFG.k)
-    rng = np.random.default_rng(41)
-    params = jax.tree_util.tree_map(
-        np.asarray, deepffm.init_params(cfg, jax.random.PRNGKey(31), "ffm"))
-    params["lr"]["w"] = rng.normal(0, 0.1, v).astype(np.float32)
-    fc, fcand = cfg.context_fields, cfg.n_fields - cfg.context_fields
-    n_cand, batch_size = 64, 8
-    n_batches = 2 if quick else 4
-    passes = 2 if quick else 4
-    # one distinct hot context per slot: every request forms its own dedup
-    # group of one fresh-candidate chunk, so the forward call shape is the
-    # (batch_size, n_cand) bucket the roofline is lowered at
-    ctxs = [(rng.integers(0, v, fc).astype(np.int32),
-             rng.normal(1, 0.25, fc).astype(np.float32))
-            for _ in range(batch_size)]
-
-    def make_batches(n):
-        out = []
-        for _ in range(n):
-            reqs = []
-            for slot in range(batch_size):
-                ci, cv = ctxs[slot]
-                ki = rng.integers(0, v, (n_cand, fcand)).astype(np.int32)
-                kv = rng.normal(1, 0.25, (n_cand, fcand)).astype(np.float32)
-                reqs.append((ci, cv, ki, kv))
-            out.append(reqs)
-        return out
-
-    warm, meas = make_batches(2), make_batches(n_batches)
-    candidates = sum(r[2].shape[0] for reqs in meas for r in reqs)
-    streams = auto_parallel_workers()
-
-    def make_engine(fused, parallel):
-        return InferenceEngine(cfg, "ffm", backend="pallas", params=params,
-                               prefix_stride=4, quantized=True,
-                               host_gather=True, fused=fused,
-                               parallel=parallel,
-                               warmup_buckets=(batch_size, n_cand))
-
-    # per-stream arms pinned parallel=1; aggregate arms at the auto worker
-    # count (same engine objects when the box has one core)
-    engines = {"staged_q8": make_engine(False, 1),
-               "fused_q8": make_engine(True, 1)}
-    if streams > 1:
-        agg_engines = {"staged_q8": make_engine(False, streams),
-                       "fused_q8": make_engine(True, streams)}
-    else:
-        agg_engines = engines
-    outs = {}
-    for name, eng in engines.items():
-        for reqs in warm:  # cache fill; meas shapes already warmed
-            eng.score_batch(reqs)
-        outs[name] = eng.score_batch(meas[0])
-    if agg_engines is not engines:
-        for eng in agg_engines.values():
-            for reqs in warm:
-                eng.score_batch(reqs)
-    times = {name: [] for name in engines}
-    agg_times = {name: [] for name in engines}
-    for _ in range(passes):  # interleaved: noise hits every arm equally
-        for name, eng in engines.items():
-            t0 = time.perf_counter()
-            for reqs in meas:
-                eng.score_batch(reqs)
-            times[name].append(time.perf_counter() - t0)
-        if agg_engines is not engines:
-            for name, eng in agg_engines.items():
-                t0 = time.perf_counter()
-                for reqs in meas:
-                    eng.score_batch(reqs)
-                agg_times[name].append(time.perf_counter() - t0)
-
-    # parity, two layers: fused vs the staged chain on the *same* quantized
-    # tables (the fused rewrite's own error budget), and both vs the direct
-    # f32 forward (the quantization envelope the engine already promises)
-    qt = engines["fused_q8"].params
-    eps = Q.row_max_error(qt["ffm"]["emb"])
-    lr_eps = Q.block_max_error(qt["lr"]["w"])
-    absmax = float(np.abs(params["ffm"]["emb"]).max())
-    vmax = float(max(max(np.abs(r[1]).max(), np.abs(r[3]).max())
-                     for r in meas[0]))
-    fused_tol = Q.fused_logit_tolerance(cfg, absmax, eps, vmax=vmax)
-    pair_tol = Q.pair_logit_tolerance(cfg, absmax, eps, vmax, lr_eps)
-    dev_vs_staged = float(max(
-        np.max(np.abs(np.asarray(outs["fused_q8"][r])
-                      - np.asarray(outs["staged_q8"][r])))
-        for r in range(batch_size)))
-    dev_vs_f32 = 0.0  # the fused arm — the new path — vs the f32 oracle
-    for r, (ci, cv, ki, kv) in enumerate(meas[0]):
-        idx = np.concatenate(
-            [np.broadcast_to(ci, (ki.shape[0], fc)), ki], axis=1)
-        val = np.concatenate(
-            [np.broadcast_to(cv, (kv.shape[0], fc)), kv], axis=1)
-        want = np.asarray(deepffm.forward(cfg, params, idx, val, "ffm"))
-        dev_vs_f32 = max(dev_vs_f32, float(np.max(np.abs(
-            np.asarray(outs["fused_q8"][r]) - want))))
-
-    # the bucket the traffic compiles to, and the roofline per arm
-    plan = engines["fused_q8"].plan
-    rb, nb = plan.bucket(batch_size), plan.bucket(n_cand)
-    bw = RL.measure_cpu_bandwidth()
-    agg_bw = (RL.measure_cpu_bandwidth(streams=streams)
-              if streams > 1 else bw)
-    # fresh slates, one context per slot: every padded slot is one unique
-    # deduped candidate row, so unique_rows == the unpadded row count
-    unique_rows = batch_size * n_cand
-    results = {}
-    for name, eng in engines.items():
-        med = float(np.median(times[name]))
-        # 1-core box: the aggregate IS the per-stream measurement (the auto
-        # policy disables splitting), not an independent remeasure
-        agg_med = (med if agg_engines is engines
-                   else float(np.median(agg_times[name])))
-        pps = candidates / med
-        roof = RL.serving_roofline(
-            eng, rb=rb, nb=nb, scenario=name,
-            measured_preds_per_s=pps,
-            bandwidth_bytes_per_s=bw,
-            unique_rows=unique_rows,
-            streams=streams,
-            aggregate_measured_preds_per_s=candidates / agg_med,
-            aggregate_bandwidth_bytes_per_s=agg_bw)
-        results[name] = {
-            "seconds_median_pass": med,
-            "us_per_batch": med / n_batches * 1e6,
-            "predictions_per_s": pps,
-            "aggregate_predictions_per_s": candidates / agg_med,
-            "roofline": roof.to_dict(),
-        }
-    for eng in engines.values():
-        eng.close()
-    if agg_engines is not engines:
-        for eng in agg_engines.values():
-            eng.close()
-    staged_bpp = results["staged_q8"]["roofline"]["bytes_per_prediction"]
-    fused_bpp = results["fused_q8"]["roofline"]["bytes_per_prediction"]
-    return {
-        "traffic": {"hash_space": v, "n_cand": n_cand,
-                    "batch_size": batch_size, "n_batches": n_batches,
-                    "passes": passes, "bucket": [rb, nb],
-                    "unique_rows": unique_rows},
-        "bandwidth_bytes_per_s": bw,
-        "streams": streams,
-        "aggregate_bandwidth_bytes_per_s": agg_bw,
-        **results,
-        "fused_vs_staged_dev": dev_vs_staged,
-        "fused_logit_tolerance": fused_tol,
-        "fused_vs_f32_dev": dev_vs_f32,
-        "f32_tolerance": pair_tol + fused_tol,
-        "acceptance": {
-            "fused_preds_per_s_improved":
-                results["fused_q8"]["predictions_per_s"]
-                > results["staged_q8"]["predictions_per_s"],
-            "fused_fewer_bytes_per_prediction": fused_bpp < staged_bpp,
-            "fused_within_staged_tolerance": dev_vs_staged <= fused_tol,
-            "fused_within_f32_tolerance": dev_vs_f32 <= pair_tol + fused_tol,
         },
     }
 

@@ -20,12 +20,11 @@ BENCHES = (
     ("fig5_simd", "benchmarks.bench_simd"),
     ("fig6_patcher", "benchmarks.bench_patcher"),
     ("sec4.1_prefetch", "benchmarks.bench_prefetch"),
-    ("roofline", "benchmarks.roofline_report"),
 )
 
 
-SMOKE = ("serving_engine", "training_pipeline",
-         "roofline")  # fast CI smoke (implies --quick)
+# fast CI smoke (implies --quick)
+SMOKE = ("serving_engine", "training_pipeline")
 
 
 def check_scenarios(mod) -> list:

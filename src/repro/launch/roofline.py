@@ -142,192 +142,6 @@ class RooflineReport:
         return d
 
 
-# ---------------------------------------------------------------------------
-# CPU serving roofline (paper regime: single-box preds/s vs memory bandwidth)
-# ---------------------------------------------------------------------------
-
-def measure_cpu_bandwidth(nbytes: int = 1 << 26, repeats: int = 3,
-                          streams: int = 1) -> float:
-    """Sustained host memory bandwidth in B/s, measured with a numpy block
-    copy (read + write of ``nbytes``; best of ``repeats``).
-
-    The serving roofline needs the *deployment box's* achievable bandwidth,
-    not a spec sheet: the paper's >300M preds/s claim is a bandwidth story,
-    and the boxes this repo has run on differ by >2x. A copy loop slightly
-    understates peak streaming reads but matches the gather-heavy serving
-    access pattern (every byte is both loaded and stored somewhere).
-
-    ``streams`` > 1 measures the **multi-stream** bandwidth the parallel
-    scoring pipeline competes for: that many threads each copy their own
-    ``nbytes`` block concurrently (numpy's ``copyto`` releases the GIL) and
-    the aggregate moved bytes over the slowest stream's wall time is
-    returned. On a memory-bound box this grows sublinearly with streams —
-    exactly the gap between the per-stream bound and the achievable
-    aggregate bound the multi-worker roofline reports.
-    """
-    import threading
-    import time
-
-    import numpy as np
-
-    streams = max(1, int(streams))
-    srcs = [np.ones(nbytes, np.uint8) for _ in range(streams)]
-    dsts = [np.empty_like(s) for s in srcs]
-    if streams == 1:
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            np.copyto(dsts[0], srcs[0])
-            best = min(best, time.perf_counter() - t0)
-        return 2.0 * nbytes / max(best, 1e-12)
-
-    start = threading.Barrier(streams + 1)
-
-    def copy_stream(i):
-        start.wait()
-        np.copyto(dsts[i], srcs[i])
-
-    best = float("inf")
-    for _ in range(repeats):
-        threads = [threading.Thread(target=copy_stream, args=(i,))
-                   for i in range(streams)]
-        for t in threads:
-            t.start()
-        start.wait()
-        t0 = time.perf_counter()
-        for t in threads:
-            t.join()
-        best = min(best, time.perf_counter() - t0)
-    return streams * 2.0 * nbytes / max(best, 1e-12)
-
-
-@dataclass
-class ServingRoofline:
-    """Bytes-per-prediction roofline for one serving configuration.
-
-    ``hlo_bytes_per_call`` comes from the engine's *deployed* compiled
-    forward (``InferenceEngine.lower_candidates_forward`` ->
-    ``hlo_analysis.analyze``); ``host_bytes_per_call`` is the engine's
-    analytic host pre-gather traffic (``InferenceEngine.host_gather_bytes``)
-    that the HLO cannot see. ``bound_preds_per_s`` is the single-thread
-    memory-bandwidth ceiling implied by bytes/prediction;
-    ``fraction_of_bound`` situates the measured throughput against it.
-
-    **Multi-stream extension** (parallel scoring pipeline): ``streams`` is
-    the worker count a parallel measurement ran with,
-    ``aggregate_bandwidth_bytes_per_s`` the bandwidth that many concurrent
-    copy streams actually sustain together
-    (:func:`measure_cpu_bandwidth` ``streams=``), and
-    ``aggregate_measured_preds_per_s`` the parallel engine's throughput.
-    ``aggregate_bound_preds_per_s`` / ``aggregate_fraction_of_bound`` then
-    bound the *whole box* the way the per-stream numbers bound one core —
-    the aggregate bound uses the measured multi-stream bandwidth, not
-    ``streams x`` the single-stream figure, because concurrent streams
-    share the memory controller.
-    """
-
-    scenario: str
-    predictions_per_call: int
-    hlo_bytes_per_call: float
-    host_bytes_per_call: float
-    hlo_flops_per_call: float
-    measured_preds_per_s: float
-    bandwidth_bytes_per_s: float
-    streams: int = 1
-    aggregate_bandwidth_bytes_per_s: Optional[float] = None
-    aggregate_measured_preds_per_s: Optional[float] = None
-
-    @property
-    def bytes_per_prediction(self) -> float:
-        return ((self.hlo_bytes_per_call + self.host_bytes_per_call)
-                / max(self.predictions_per_call, 1))
-
-    @property
-    def bound_preds_per_s(self) -> float:
-        return self.bandwidth_bytes_per_s / max(self.bytes_per_prediction, 1e-12)
-
-    @property
-    def fraction_of_bound(self) -> float:
-        return self.measured_preds_per_s / max(self.bound_preds_per_s, 1e-12)
-
-    @property
-    def aggregate_bound_preds_per_s(self) -> Optional[float]:
-        if self.aggregate_bandwidth_bytes_per_s is None:
-            return None
-        return (self.aggregate_bandwidth_bytes_per_s
-                / max(self.bytes_per_prediction, 1e-12))
-
-    @property
-    def aggregate_fraction_of_bound(self) -> Optional[float]:
-        bound = self.aggregate_bound_preds_per_s
-        if bound is None or self.aggregate_measured_preds_per_s is None:
-            return None
-        return self.aggregate_measured_preds_per_s / max(bound, 1e-12)
-
-    def to_dict(self) -> Dict:
-        d = dataclasses.asdict(self)
-        d.update(
-            bytes_per_prediction=self.bytes_per_prediction,
-            bound_preds_per_s=self.bound_preds_per_s,
-            fraction_of_bound=self.fraction_of_bound,
-            aggregate_bound_preds_per_s=self.aggregate_bound_preds_per_s,
-            aggregate_fraction_of_bound=self.aggregate_fraction_of_bound,
-        )
-        return d
-
-
-def serving_roofline(engine, *, rb: int, nb: int, scenario: str,
-                     measured_preds_per_s: float,
-                     bandwidth_bytes_per_s: Optional[float] = None,
-                     unique_rows: Optional[int] = None,
-                     streams: int = 1,
-                     aggregate_measured_preds_per_s: Optional[float] = None,
-                     aggregate_bandwidth_bytes_per_s: Optional[float] = None
-                     ) -> ServingRoofline:
-    """Build a :class:`ServingRoofline` from a live engine: lowers the
-    deployed candidate forward at the (rb, nb) bucket, walks its optimized
-    HLO for per-call flops/bytes, and adds the host pre-gather traffic
-    (``unique_rows`` — deduped candidate rows per call — tightens the
-    compact-grid term; see ``InferenceEngine.host_gather_bytes``). Raises
-    (loudly) if the engine cannot produce HLO — a roofline over a stub
-    would describe a path requests never run.
-
-    Pass ``streams`` + ``aggregate_measured_preds_per_s`` for a parallel
-    (multi-worker) measurement: the aggregate bound is computed against the
-    measured ``streams``-way bandwidth
-    (``aggregate_bandwidth_bytes_per_s``, measured here when omitted)."""
-    from repro.launch import hlo_analysis
-
-    lowered = engine.lower_candidates_forward(rb, nb)
-    hlo_text = lowered.compile().as_text()
-    if not hlo_text:
-        raise RuntimeError("engine produced no compiled HLO to analyze")
-    a = hlo_analysis.analyze(hlo_text)
-    if bandwidth_bytes_per_s is None:
-        bandwidth_bytes_per_s = measure_cpu_bandwidth()
-    streams = max(1, int(streams))
-    if streams > 1 and aggregate_bandwidth_bytes_per_s is None:
-        aggregate_bandwidth_bytes_per_s = measure_cpu_bandwidth(
-            streams=streams)
-    return ServingRoofline(
-        scenario=scenario,
-        predictions_per_call=rb * nb,
-        hlo_bytes_per_call=float(a["bytes_per_device"]),
-        host_bytes_per_call=float(
-            engine.host_gather_bytes(rb, nb, unique_rows=unique_rows)),
-        hlo_flops_per_call=float(a["flops_per_device"]),
-        measured_preds_per_s=float(measured_preds_per_s),
-        bandwidth_bytes_per_s=float(bandwidth_bytes_per_s),
-        streams=streams,
-        aggregate_bandwidth_bytes_per_s=(
-            None if aggregate_bandwidth_bytes_per_s is None
-            else float(aggregate_bandwidth_bytes_per_s)),
-        aggregate_measured_preds_per_s=(
-            None if aggregate_measured_preds_per_s is None
-            else float(aggregate_measured_preds_per_s)),
-    )
-
-
 def build_report(*, arch: str, shape: str, mesh_name: str, chips: int,
                  cost: Dict, hlo_text: str, model_flops: float,
                  memory_analysis=None) -> RooflineReport:

@@ -154,6 +154,23 @@ Cross-request candidate dedup packs the microbatch's ``(group, idx, val)``
 rows into one contiguous int32 matrix and dedups with ``np.unique`` on a
 void view — no per-row Python hashing on the hot path.
 
+**Tracing.** Each ``score_batch`` call takes a batch id and opens
+``jax.profiler.TraceAnnotation`` spans for its phases, every one carrying
+that id as ``call``: ``serve.score_batch`` (the whole call),
+``serve.resolve`` (context tokens, trie lookups and inserts),
+``serve.tails`` (host tail arithmetic, one span per miss group),
+``serve.dedup`` (packed dedup, chunk layout, candidate blocks and grids),
+``serve.prepare`` (padding, stacking and the host pre-gather of one chunk
+span; on a pool thread when the batch is split), ``serve.pool_wait`` (the
+caller waiting for a prepare), ``serve.launch`` (the jitted call, which
+copies its host arguments to the device), ``serve.device_wait`` (waiting for
+the device and copying the results back) and ``serve.finish`` (fused cache
+inserts, scatter-back, stats). The profiler records them only while
+``jax.profiler.start_trace`` runs; their wall seconds always add up in
+``ServeStats.phase_s`` (:class:`CallPhases`). ``ServeStats.host_arg_bytes``
+and ``slots_scored`` count the host bytes handed to the jitted forwards and
+the padded slots they computed.
+
 **Machine-checked invariants (PR 10).** The concurrency and purity
 contracts this module leans on — the lock partial order (`_pipe_lock` and
 `_lock` sit *under* the pipe's `_ingest_lock`; see
@@ -166,6 +183,8 @@ ROADMAP.md.
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import threading
 import time
@@ -178,6 +197,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.config import FFMConfig
 from repro.core import deepffm, ffm
@@ -200,7 +220,11 @@ class ServeStats:
     ``ctx_partials_full`` counts contexts computed from scratch (no cached
     prefix) and ``ctx_tail_fields`` the total context fields actually
     computed — the prefix cache shrinks both relative to an exact-match
-    cache on prefix-sharing traffic.
+    cache on prefix-sharing traffic. ``host_arg_bytes`` counts the bytes of
+    host numpy arrays handed to the jitted forwards (a device-resident
+    ``jax.Array`` argument counts 0), ``slots_scored`` the padded
+    ``rows x candidates`` slots those forwards computed, and ``phase_s`` the
+    wall seconds spent in each ``serve.*`` span (see :class:`CallPhases`).
     """
 
     requests: int = 0
@@ -211,6 +235,9 @@ class ServeStats:
     update_bytes: int = 0
     ctx_partials_full: int = 0
     ctx_tail_fields: int = 0
+    host_arg_bytes: int = 0
+    slots_scored: int = 0
+    phase_s: Dict[str, float] = field(default_factory=dict)
     # fault-tolerance counters (PR 9) — populated by the ShardRouter:
     degraded_responses: int = 0  # responses with >=1 zero-rows slice
     deadline_misses: int = 0     # responses that gave a slice up at deadline
@@ -252,12 +279,19 @@ class ServeStats:
         self.update_bytes += other.update_bytes
         self.ctx_partials_full += other.ctx_partials_full
         self.ctx_tail_fields += other.ctx_tail_fields
+        self.host_arg_bytes += other.host_arg_bytes
+        self.slots_scored += other.slots_scored
+        self.add_phases(other.phase_s)
         self.degraded_responses += other.degraded_responses
         self.deadline_misses += other.deadline_misses
         self.hedged_calls += other.hedged_calls
         self.failovers += other.failovers
         self.last_degraded = self.last_degraded or other.last_degraded
         self._latencies_s.extend(other._latencies_s)
+
+    def add_phases(self, seconds: Dict[str, float]) -> None:
+        for name, s in seconds.items():
+            self.phase_s[name] = self.phase_s.get(name, 0.0) + s
 
     @property
     def dedup_saved(self) -> int:
@@ -285,6 +319,49 @@ class ServeStats:
     @property
     def p99_ms(self) -> float:
         return self.latency_ms(99.0)
+
+
+class CallPhases:
+    """The phases of one ``score_batch`` call. Each :meth:`span` is a
+    ``jax.profiler.TraceAnnotation`` carrying the call's batch id as
+    ``call`` (recorded only while a profiler trace runs), and its wall time
+    adds to the call's :meth:`totals`, from any thread: the engine folds
+    those into ``ServeStats.phase_s`` once the call returns."""
+
+    def __init__(self, call: int):
+        self.call = call
+        self._seconds: Dict[str, float] = {}  # guarded-by: _lock
+        self._lock = threading.Lock()
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation(name, call=self.call):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            with self._lock:
+                self._seconds[name] = self._seconds.get(name, 0.0) + dt
+
+    def totals(self) -> Dict[str, float]:
+        """Seconds per span name, summed over this call's threads."""
+        with self._lock:
+            return dict(self._seconds)
+
+
+def _span(phases: Optional[CallPhases], name: str):
+    """``phases.span(name)``; no span for work outside a ``score_batch``
+    call (``phases`` None: prewarm, a bare ``ScoringPool.run``)."""
+    return contextlib.nullcontext() if phases is None else phases.span(name)
+
+
+def host_arg_nbytes(args) -> int:
+    """Bytes of the host numpy arrays among a forward call's arguments: what
+    crosses to the device when the call runs. A ``jax.Array`` already on the
+    device counts 0."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(args)
+               if isinstance(x, (np.ndarray, np.generic)))
 
 
 # ---------------------------------------------------------------------------
@@ -359,10 +436,12 @@ class ScoringPool:
         """Raw executor submit — the ShardRouter's scatter-gather fan-out."""
         return self._ex.submit(fn, *args)
 
-    def run(self, prepares: Sequence, dispatch, cleanup=None) -> list:
+    def run(self, prepares: Sequence, dispatch, cleanup=None,
+            phases: Optional[CallPhases] = None) -> list:
         """Pipeline ``prepares`` (pool threads, bounded look-ahead) against
         ``dispatch`` (caller thread, fixed order); returns dispatch results
-        in prepare order.
+        in prepare order. Each wait for a prepare is a ``serve.pool_wait``
+        span of the caller's ``phases``.
 
         Exception safety: if any prepare or dispatch raises, the remaining
         in-flight prepares are *drained* — each completed result is handed to
@@ -374,13 +453,18 @@ class ScoringPool:
         window = self.workers + 1
         pending: deque = deque()
         out = []
+
+        def next_prepared():
+            with _span(phases, "serve.pool_wait"):
+                return pending.popleft().result()
+
         try:
             for prep in prepares:
                 pending.append(self._ex.submit(prep))
                 if len(pending) >= window:
-                    out.append(dispatch(pending.popleft().result()))
+                    out.append(dispatch(next_prepared()))
             while pending:
-                out.append(dispatch(pending.popleft().result()))
+                out.append(dispatch(next_prepared()))
         except BaseException:
             while pending:
                 fut = pending.popleft()
@@ -776,6 +860,8 @@ class InferenceEngine:
         self.hits = 0    # guarded-by: _lock
         self.misses = 0  # guarded-by: _lock
         self.stats = ServeStats()  # guarded-by: _lock
+        # batch ids: every span of one score_batch call carries the same one
+        self._call_ids = itertools.count()
         self.parallel = (auto_parallel_workers() if parallel is None
                          else max(1, int(parallel)))
         self._scoring_pool = scoring_pool  # guarded-by: _lock
@@ -1008,7 +1094,8 @@ class InferenceEngine:
     def _resolve_contexts(self, ctxs: List[Tuple[Tuple[bytes, ...],
                                                  np.ndarray, np.ndarray]],
                           params, generation: int,
-                          record_stats: bool = True
+                          record_stats: bool = True,
+                          phases: Optional[CallPhases] = None
                           ) -> Tuple[List[Dict], List[bool]]:
         """Full-depth prefix states for each unique (tokens, idx, val) context,
         plus a full-depth-hit flag per context.
@@ -1022,7 +1109,9 @@ class InferenceEngine:
         one representative per distinct prefix is computed (and inserted)
         first, and the rest re-look-up in the next round to reuse it — the
         sequential walk a radix tree would do, restructured to keep the tail
-        computation batched.
+        computation batched. The tail arithmetic of each miss group is a
+        ``serve.tails`` span of ``phases`` (the trie work between them stays
+        in the caller's ``serve.resolve``).
         """
         fc = self.cfg.context_fields
         with self._lock:
@@ -1071,12 +1160,13 @@ class InferenceEngine:
             for depth, members in sorted(miss_groups.items()):
                 t = fc - depth
                 fresh = []
-                for i in members:
-                    base = (ffm.slice_context_prefix(looked[i][1], depth)
-                            if looked[i][1] is not None else empty)
-                    fresh.append(ffm.extend_context_prefix_np(
-                        self.cfg, emb_h, lr_h, base,
-                        ctxs[i][1][depth:], ctxs[i][2][depth:]))
+                with _span(phases, "serve.tails"):
+                    for i in members:
+                        base = (ffm.slice_context_prefix(looked[i][1], depth)
+                                if looked[i][1] is not None else empty)
+                        fresh.append(ffm.extend_context_prefix_np(
+                            self.cfg, emb_h, lr_h, base,
+                            ctxs[i][1][depth:], ctxs[i][2][depth:]))
                 with self._lock:
                     if record_stats:
                         self.stats.ctx_partials_full += sum(
@@ -1093,7 +1183,8 @@ class InferenceEngine:
     def _resolve_contexts_fused(self, ctxs: List[Tuple[Tuple[bytes, ...],
                                                        np.ndarray, np.ndarray]],
                                 params, generation: int,
-                                record_stats: bool = True):
+                                record_stats: bool = True,
+                                phases: Optional[CallPhases] = None):
         """Gather-only context resolution for the fused scoring path.
 
         Returns ``(states, insert_info, full_hit)``: per context a stackable
@@ -1108,7 +1199,9 @@ class InferenceEngine:
         can't chain off each other's fresh inserts — each extends
         independently from its deepest *already-cached* prefix. The cache
         still learns (inserts land post-scoring), so steady-state traffic
-        converges to the same hit depths.
+        converges to the same hit depths. The pass over the contexts, where
+        the misses' rows are gathered, is one ``serve.tails`` span of
+        ``phases``.
         """
         fc = self.cfg.context_fields
         states: List[Optional[Dict]] = [None] * len(ctxs)
@@ -1120,25 +1213,27 @@ class InferenceEngine:
         empty = ffm.empty_context_prefix_np(
             self.cfg, ffm.table_dtype(params["ffm"]["emb"]))
         n_full = tails = 0
-        for i, (toks, ci, cv) in enumerate(ctxs):
-            depth, state = looked[i]
-            if depth == fc:
-                full_hit[i] = True
-                states[i] = {
-                    "emb": state["emb"], "val": state["val"],
-                    "depth": np.int32(fc),
-                    "pair_sum": np.float32(np.asarray(state["pairs"]).sum()),
-                    "lr_terms": state["lr_terms"],
-                }
-                continue
-            base = (ffm.slice_context_prefix(state, depth)
-                    if state is not None else empty)
-            states[i] = ffm.fused_context_state_np(
-                self.cfg, emb_h, lr_h, base, ci[depth:], cv[depth:])
-            insert_info[i] = (depth,
-                              np.array(base["pairs"], np.float32, copy=True))
-            n_full += depth == 0
-            tails += fc - depth
+        with _span(phases, "serve.tails"):
+            for i, (toks, ci, cv) in enumerate(ctxs):
+                depth, state = looked[i]
+                if depth == fc:
+                    full_hit[i] = True
+                    states[i] = {
+                        "emb": state["emb"], "val": state["val"],
+                        "depth": np.int32(fc),
+                        "pair_sum": np.float32(
+                            np.asarray(state["pairs"]).sum()),
+                        "lr_terms": state["lr_terms"],
+                    }
+                    continue
+                base = (ffm.slice_context_prefix(state, depth)
+                        if state is not None else empty)
+                states[i] = ffm.fused_context_state_np(
+                    self.cfg, emb_h, lr_h, base, ci[depth:], cv[depth:])
+                insert_info[i] = (depth, np.array(base["pairs"], np.float32,
+                                                  copy=True))
+                n_full += depth == 0
+                tails += fc - depth
         if record_stats:
             with self._lock:
                 for (depth, _), info in zip(looked, insert_info):
@@ -1221,6 +1316,19 @@ class InferenceEngine:
         self._require_params()
         if not requests:
             return []
+        phases = CallPhases(next(self._call_ids))
+        with phases.span("serve.score_batch"):
+            results = self._score_requests(requests, phases)
+        with self._lock:
+            self.stats.add_phases(phases.totals())
+        return results
+
+    def _score_requests(self, requests: Sequence[Tuple],
+                        phases: CallPhases) -> List[np.ndarray]:
+        """The body of :meth:`score_batch`; its phases are the spans
+        ``serve.resolve``, ``serve.tails``, ``serve.dedup``,
+        ``serve.prepare``, ``serve.pool_wait``, ``serve.launch``,
+        ``serve.device_wait`` and ``serve.finish`` of ``phases``."""
         t0 = time.perf_counter()
         params, generation = self._weights
 
@@ -1243,119 +1351,129 @@ class InferenceEngine:
                  slate(ki, np.int32), slate(kv, np.float32))
                 for ci, cv, ki, kv in requests]
 
-        # unique contexts across the microbatch
-        u_of: List[int] = []
-        u_index: Dict[Tuple[bytes, ...], int] = {}
-        u_ctxs: List[Tuple[Tuple[bytes, ...], np.ndarray, np.ndarray]] = []
-        for ci, cv, ki, kv in reqs:
-            toks = context_tokens(ci, cv)
-            u = u_index.get(toks)
-            if u is None:
-                u = u_index[toks] = len(u_ctxs)
-                u_ctxs.append((toks, ci, cv))
-            u_of.append(u)
+        with phases.span("serve.resolve"):
+            # unique contexts across the microbatch
+            u_of: List[int] = []
+            u_index: Dict[Tuple[bytes, ...], int] = {}
+            u_ctxs: List[Tuple[Tuple[bytes, ...], np.ndarray,
+                               np.ndarray]] = []
+            for ci, cv, ki, kv in reqs:
+                toks = context_tokens(ci, cv)
+                u = u_index.get(toks)
+                if u is None:
+                    u = u_index[toks] = len(u_ctxs)
+                    u_ctxs.append((toks, ci, cv))
+                u_of.append(u)
 
-        fc = self.cfg.context_fields
-        if self.fused:
-            states, insert_info, full_hit = self._resolve_contexts_fused(
-                u_ctxs, params, generation)
-        else:
-            states, full_hit = self._resolve_contexts(u_ctxs, params, generation)
-        # hit/miss bookkeeping matches the flat cache: first request of an
-        # uncached context is the miss, every other request this batch (and
-        # every full-depth match) is a hit
-        seen_full = dict(enumerate(full_hit))
-        with self._lock:
-            for u in u_of:
-                if seen_full[u]:
-                    self.hits += 1
-                else:
-                    self.misses += 1
-                    seen_full[u] = True
-
-        # candidate rows: dedup identical (context, candidate) pairs across
-        # requests, or keep one row-group per request (PR 1 behaviour)
-        if self.dedup:
-            group_of_req = u_of
-            n_groups = len(u_ctxs)
-            group_state = states
-        else:
-            group_of_req = list(range(len(reqs)))
-            n_groups = len(reqs)
-            group_state = [states[u] for u in u_of]
-        counts = np.asarray([r[2].shape[0] for r in reqs], np.int64)
-        total = int(counts.sum())
-        if total == 0:  # every request carried an empty slate
+            if self.fused:
+                states, insert_info, full_hit = (
+                    self._resolve_contexts_fused(u_ctxs, params, generation,
+                                                 phases=phases))
+            else:
+                states, full_hit = self._resolve_contexts(
+                    u_ctxs, params, generation, phases=phases)
+            # hit/miss bookkeeping matches the flat cache: first request of
+            # an uncached context is the miss, every other request this batch
+            # (and every full-depth match) is a hit
+            seen_full = dict(enumerate(full_hit))
             with self._lock:
-                self.stats.record(time.perf_counter() - t0, 0,
-                                  requests=len(reqs))
-            return [np.zeros((0,), np.float32) for _ in reqs]
-        group_of_row = np.repeat(np.asarray(group_of_req, np.int64), counts)
-        ki_all = np.concatenate([r[2] for r in reqs])      # (total, Fcand)
-        kv_all = np.concatenate([r[3] for r in reqs])
-        if self.dedup:
-            # packed-array dedup: one contiguous (group | idx | val-bits)
-            # int32 matrix viewed as void rows for np.unique — identical
-            # semantics to per-row byte keys, no Python-level row loop
-            mat = np.empty((total, 1 + 2 * fcand), np.int32)
-            mat[:, 0] = group_of_row
-            mat[:, 1:1 + fcand] = ki_all
-            mat[:, 1 + fcand:] = kv_all.view(np.int32)
-            packed = np.ascontiguousarray(mat).view(
-                np.dtype((np.void, mat.itemsize * mat.shape[1])))[:, 0]
-            _, first, inverse = np.unique(packed, return_index=True,
-                                          return_inverse=True)
-        else:
-            first = inverse = np.arange(total)
-        u_group = group_of_row[first]
-        n_rows = int(first.size)
+                for u in u_of:
+                    if seen_full[u]:
+                        self.hits += 1
+                    else:
+                        self.misses += 1
+                        seen_full[u] = True
 
-        # a dedup group unions candidates from several requests and can exceed
-        # the per-request bucket; chunk groups to the request-level bucket so
-        # padded work never exceeds the no-dedup layout and the compiled shape
-        # set stays the closed per-request one (see warmup)
-        nb = self.plan.bucket(int(counts.max()))
-        order = np.argsort(u_group, kind="stable")
-        gcounts = np.bincount(u_group, minlength=n_groups)
-        gstarts = np.concatenate([[0], np.cumsum(gcounts)[:-1]])
-        pos = np.empty(n_rows, np.int64)  # rank of each unique row in its group
-        pos[order] = np.arange(n_rows) - np.repeat(gstarts, gcounts)
-        chunks_per_g = -(-gcounts // nb)
-        chunk_base = np.concatenate([[0], np.cumsum(chunks_per_g)[:-1]])
-        n_chunks = int(chunks_per_g.sum())
-        row_of_u = chunk_base[u_group] + pos // nb
-        slot_of_u = pos % nb
+        with phases.span("serve.dedup"):
+            # candidate rows: dedup identical (context, candidate) pairs
+            # across requests, or keep one row-group per request
+            if self.dedup:
+                group_of_req = u_of
+                n_groups = len(u_ctxs)
+                group_state = states
+            else:
+                group_of_req = list(range(len(reqs)))
+                n_groups = len(reqs)
+                group_state = [states[u] for u in u_of]
+            counts = np.asarray([r[2].shape[0] for r in reqs], np.int64)
+            total = int(counts.sum())
+            if total == 0:  # every request carried an empty slate
+                with self._lock:
+                    self.stats.record(time.perf_counter() - t0, 0,
+                                      requests=len(reqs))
+                return [np.zeros((0,), np.float32) for _ in reqs]
+            group_of_row = np.repeat(np.asarray(group_of_req, np.int64),
+                                     counts)
+            ki_all = np.concatenate([r[2] for r in reqs])      # (total, Fcand)
+            kv_all = np.concatenate([r[3] for r in reqs])
+            if self.dedup:
+                # packed-array dedup: one contiguous (group | idx | val-bits)
+                # int32 matrix viewed as void rows for np.unique — identical
+                # semantics to per-row byte keys, no Python-level row loop
+                mat = np.empty((total, 1 + 2 * fcand), np.int32)
+                mat[:, 0] = group_of_row
+                mat[:, 1:1 + fcand] = ki_all
+                mat[:, 1 + fcand:] = kv_all.view(np.int32)
+                packed = np.ascontiguousarray(mat).view(
+                    np.dtype((np.void, mat.itemsize * mat.shape[1])))[:, 0]
+                _, first, inverse = np.unique(packed, return_index=True,
+                                              return_inverse=True)
+            else:
+                first = inverse = np.arange(total)
+            u_group = group_of_row[first]
+            n_rows = int(first.size)
 
-        # unpadded (n_chunks, nb, Fcand) candidate blocks, built once; the
-        # span scorer pads each contiguous chunk span to its own power-of-two
-        # row bucket (a single span of every chunk reproduces the padded
-        # single-stream call exactly)
-        ki_c = np.zeros((n_chunks, nb, fcand), np.int32)
-        kv_c = np.zeros((n_chunks, nb, fcand), np.float32)
-        ki_c[row_of_u, slot_of_u] = ki_all[first]
-        kv_c[row_of_u, slot_of_u] = kv_all[first]
-        grids_c = self._compact_grids(params, ki_all[first], row_of_u,
-                                      slot_of_u, n_chunks, nb, fcand)
+            # a dedup group unions candidates from several requests and can
+            # exceed the per-request bucket; chunk groups to the request-level
+            # bucket so padded work never exceeds the no-dedup layout and the
+            # compiled shape set stays the closed per-request one (see warmup)
+            nb = self.plan.bucket(int(counts.max()))
+            order = np.argsort(u_group, kind="stable")
+            gcounts = np.bincount(u_group, minlength=n_groups)
+            gstarts = np.concatenate([[0], np.cumsum(gcounts)[:-1]])
+            pos = np.empty(n_rows, np.int64)  # rank of each row in its group
+            pos[order] = np.arange(n_rows) - np.repeat(gstarts, gcounts)
+            chunks_per_g = -(-gcounts // nb)
+            chunk_base = np.concatenate([[0], np.cumsum(chunks_per_g)[:-1]])
+            n_chunks = int(chunks_per_g.sum())
+            row_of_u = chunk_base[u_group] + pos // nb
+            slot_of_u = pos % nb
 
-        chunk_group = np.repeat(np.arange(n_groups), chunks_per_g)
-        chunk_state = [group_state[g] for g in chunk_group]
+            # unpadded (n_chunks, nb, Fcand) candidate blocks, built once;
+            # the span scorer pads each contiguous chunk span to its own
+            # power-of-two row bucket (a single span of every chunk
+            # reproduces the padded single-stream call exactly)
+            ki_c = np.zeros((n_chunks, nb, fcand), np.int32)
+            kv_c = np.zeros((n_chunks, nb, fcand), np.float32)
+            ki_c[row_of_u, slot_of_u] = ki_all[first]
+            kv_c[row_of_u, slot_of_u] = kv_all[first]
+            grids_c = self._compact_grids(params, ki_all[first], row_of_u,
+                                          slot_of_u, n_chunks, nb, fcand)
+
+            chunk_group = np.repeat(np.arange(n_groups), chunks_per_g)
+            chunk_state = [group_state[g] for g in chunk_group]
+        batch_stats = ServeStats()  # the forwards' counters land here too
         out, ctx_dots = self._score_spans(params, chunk_state, ki_c, kv_c,
-                                          grids_c, self._plan_spans(n_chunks))
-        if self.fused:
-            self._insert_fused_misses(u_ctxs, states, insert_info,
-                                      chunk_group, u_of, ctx_dots, generation)
-        # plain numpy scatter-back (no per-request device gathers)
-        flat = out[row_of_u[inverse], slot_of_u[inverse]]
-        offs = np.concatenate([[0], np.cumsum(counts)])
-        results = [flat[offs[i]:offs[i + 1]] for i in range(len(reqs))]
-        # per-batch stats accumulate outside the lock and merge in one shot:
-        # one record per caller-visible batch no matter how many chunk spans
-        # the parallel pipeline dispatched (see ServeStats.merge)
-        batch_stats = ServeStats()
-        batch_stats.rows_scored = n_rows
-        batch_stats.record(time.perf_counter() - t0, total, requests=len(reqs))
-        with self._lock:
-            self.stats.merge(batch_stats)
+                                          grids_c, self._plan_spans(n_chunks),
+                                          batch_stats, phases)
+        with phases.span("serve.finish"):
+            if self.fused:
+                self._insert_fused_misses(u_ctxs, states, insert_info,
+                                          chunk_group, u_of, ctx_dots,
+                                          generation)
+            # plain numpy scatter-back (no per-request device gathers)
+            flat = out[row_of_u[inverse], slot_of_u[inverse]]
+            offs = np.concatenate([[0], np.cumsum(counts)])
+            results = [flat[offs[i]:offs[i + 1]] for i in range(len(reqs))]
+            # per-batch stats accumulate outside the lock and merge in one
+            # shot: one record per caller-visible batch no matter how many
+            # chunk spans the parallel pipeline dispatched (see
+            # ServeStats.merge)
+            batch_stats.rows_scored = n_rows
+            batch_stats.record(time.perf_counter() - t0, total,
+                               requests=len(reqs))
+            with self._lock:
+                self.stats.merge(batch_stats)
         return results
 
     # -- parallel scoring pipeline ------------------------------------------
@@ -1416,7 +1534,9 @@ class InferenceEngine:
         z_c[row_of_u, slot_of_u] = emb_h["zero"][ki_u]
         return s_c, z_c
 
-    def _score_spans(self, params, chunk_state, ki_c, kv_c, grids_c, spans):
+    def _score_spans(self, params, chunk_state, ki_c, kv_c, grids_c, spans,
+                     stats: ServeStats,
+                     phases: Optional[CallPhases] = None):
         """Score contiguous chunk spans and reassemble ``(logits (n_chunks,
         nb), ctx_dots | None)`` in fixed chunk order — the parallel pipeline's
         core. One span runs inline (exactly the single-stream path). Several
@@ -1427,7 +1547,12 @@ class InferenceEngine:
         and sliced back to its true length, the reassembled block is
         bit-identical for every worker count: per-row outputs of all the
         jitted forwards are invariant to the row-bucket size, and all spans
-        share this batch's one resolved context snapshot."""
+        share this batch's one resolved context snapshot.
+
+        Each forward call adds its host argument bytes and padded slots to
+        ``stats``; its phases are the ``serve.prepare``, ``serve.launch``
+        and ``serve.device_wait`` spans of ``phases``.
+        """
         n_chunks = ki_c.shape[0]
         pool = self._get_pool() if len(spans) > 1 else None
         codes_tbl = None
@@ -1446,6 +1571,10 @@ class InferenceEngine:
                 [x, np.zeros((rb_s - m,) + x.shape[1:], x.dtype)])
 
         def prepare(lo, hi):
+            with _span(phases, "serve.prepare"):
+                return build(lo, hi)
+
+        def build(lo, hi):
             m = hi - lo
             rb_s = self.plan.bucket(m, minimum=1)
             ki_b = pad_rows(ki_c[lo:hi], rb_s, m)
@@ -1465,22 +1594,27 @@ class InferenceEngine:
                     ki_b.shape + codes_tbl.shape[1:], codes_tbl.dtype)
             fn_args = self._forward_args(params, stacked, ki_b, kv_b,
                                          grids=grids, out_codes=out_codes)
-            return fn_args, m, out_codes
+            return fn_args, m, out_codes, rb_s * ki_b.shape[1]
 
         def dispatch(prepared):
-            (fn, args), m, buf = prepared
+            (fn, args), m, buf, slots = prepared
+            stats.host_arg_bytes += host_arg_nbytes(args)
+            stats.slots_scored += slots
             try:
-                fwd = jax.block_until_ready(fn(*args))
+                with _span(phases, "serve.launch"):
+                    fwd = fn(*args)
+                with _span(phases, "serve.device_wait"):
+                    fwd = jax.block_until_ready(fwd)
+                    if self.fused:
+                        out_s, dots_s = fwd
+                        return np.asarray(out_s)[:m], np.asarray(dots_s)[:m]
+                    return np.asarray(fwd)[:m], None
             finally:
                 if buf is not None:
                     # on success the computation has completed (no XLA alias);
                     # on error nothing holds the buffer either — either way it
                     # must return to the free list or the burst leaks it
                     pool.release(buf)
-            if self.fused:
-                out_s, dots_s = fwd
-                return np.asarray(out_s)[:m], np.asarray(dots_s)[:m]
-            return np.asarray(fwd)[:m], None
 
         def span_cleanup(prepared):
             # drain path (ScoringPool.run): a prepared-but-never-dispatched
@@ -1494,7 +1628,7 @@ class InferenceEngine:
             parts = [dispatch(prepare(lo, hi))]
         else:
             parts = pool.run([partial(prepare, lo, hi) for lo, hi in spans],
-                             dispatch, cleanup=span_cleanup)
+                             dispatch, cleanup=span_cleanup, phases=phases)
         if len(parts) == 1:
             out, dots = parts[0]
         else:
@@ -1510,8 +1644,8 @@ class InferenceEngine:
         its argument tuple — the host pre-gather (candidate codes/rows + LR
         sums via packed numpy gather, immune to the XLA gather cliff)
         happens here. Shared by :meth:`_candidates_forward` (calls it) and
-        :meth:`lower_candidates_forward` (lowers it for the roofline
-        report), so the analyzed HLO is exactly the deployed forward.
+        :meth:`lower_candidates_forward` (lowers it), so the lowered program
+        is exactly the deployed forward.
 
         ``grids`` is the compact-gathered padded ``(scale, zero)`` pair
         :meth:`score_batch` builds once per unique deduped row
@@ -1595,53 +1729,14 @@ class InferenceEngine:
     def lower_candidates_forward(self, rb: int, nb: int):
         """Lower (trace, don't run) the deployed candidate forward at one
         (row-bucket, candidate-bucket) shape and return the jax ``Lowered``
-        — ``.compile().as_text()`` is the optimized HLO the roofline report
-        analyzes (``launch.hlo_analysis``). Uses the same argument builder
-        as the hot path, so the analyzed program is byte-for-byte the one
-        requests run, not a stub."""
+        (its ``args_info`` gives the arguments' shapes and dtypes). Uses the
+        same argument builder as the hot path, so the lowered program is
+        byte-for-byte the one requests run, not a stub."""
         self._require_params()
         params, _ = self._weights
         cached, ki_b, kv_b = self._warmup_dummies(rb, nb)
         fn, args = self._forward_args(params, cached, ki_b, kv_b)
         return fn.lower(*args)
-
-    def host_gather_bytes(self, rb: int, nb: int,
-                          unique_rows: Optional[int] = None) -> int:
-        """Analytic bytes the *host* pre-gather stage moves per forward call
-        at one (rb, nb) bucket — the traffic the jit's HLO cannot see, added
-        to the HLO byte count for the serving roofline. Counts read + write
-        of every gathered block (numpy ``take`` copies): candidate embedding
-        rows (int8 codes, f32 rows otherwise), LR weights, and the index
-        reads. On a quantized engine the f32 ``(scale, zero)`` grids are
-        gathered once per **unique** deduped candidate row (``unique_rows``,
-        pre-padding; defaults to the padded count — the no-dedup bound) and
-        broadcast
-        into the padded block at scatter time, so they cost one read+write
-        per unique row plus one write per padded slot — the compact-grid
-        satellite's saving over the old per-padded-row grid gather. An
-        engineering estimate of the dominant streams, not a hardware
-        counter."""
-        self._require_params()
-        cfg = self.cfg
-        fcand = cfg.n_fields - cfg.context_fields
-        rows = rb * nb * fcand
-        if not self.host_gather:
-            return 0
-        emb = self.params["ffm"]["emb"]
-        lr_w = self.params["lr"]["w"]
-        lr_bytes = 1 + 2 * 4 if Q.is_block_quantized(lr_w) else 4
-        idx_bytes = 4
-        if Q.is_row_quantized(emb):
-            row_bytes = cfg.n_fields * cfg.k            # codes only
-            grid_bytes = 2 * 4                          # f32 (scale, zero)
-            u_rows = (rows if unique_rows is None
-                      else int(unique_rows) * fcand)
-            total = rows * (2 * (row_bytes + lr_bytes) + idx_bytes)
-            total += grid_bytes * (2 * u_rows + rows)   # compact R+W + scatter
-        else:
-            row_bytes = cfg.n_fields * cfg.k * 4
-            total = rows * (2 * (row_bytes + lr_bytes) + idx_bytes)
-        return int(total)
 
     _warmed_requests: Optional[int] = None  # set by warmup(); clamps prewarm
     _warmed_buckets: Optional[Tuple[int, int]] = None  # rotate() re-warms these
