@@ -58,7 +58,15 @@ composition point; each component maps to a paper section:
   (``host_gather=``, auto) that feeds already-gathered codes + summed LR
   terms to :func:`batched_candidates_forward_q8` — XLA-CPU's generic gather
   leaves its fast path above that size (the ROADMAP'd int8 gather cliff)
-  while the packed numpy gather stays flat. **Tolerance contract**: scores
+  while the packed numpy gather stays flat. An in-trace engine on an
+  accelerator holds its gather tables **device-resident per generation**:
+  each published params object is copied to the device once, before its
+  swap and off the request path (:meth:`InferenceEngine._device_params`;
+  ``ServeStats.table_uploads`` counts these copies, one per install or
+  publish), and every forward call takes that copy instead of host numpy —
+  the same bytes into the same jitted function, so scores are bit-identical.
+  On the CPU backend device memory is host memory, so no copy is kept there
+  (it would only double host RAM). **Tolerance contract**: scores
   deviate from the f32 oracle by at most the per-row/per-block
   reconstruction errors ``quantization.row_max_error`` /
   ``quantization.block_max_error`` propagated through the pair and LR sums
@@ -225,6 +233,12 @@ class ServeStats:
     ``jax.Array`` argument counts 0), ``slots_scored`` the padded
     ``rows x candidates`` slots those forwards computed, and ``phase_s`` the
     wall seconds spent in each ``serve.*`` span (see :class:`CallPhases`).
+    ``table_uploads`` counts the device-resident twins of published weights
+    an in-trace engine on an accelerator built (see
+    :meth:`InferenceEngine._device_params`) and ``table_upload_bytes`` the
+    host bytes they copied: one per install, publish or construction with
+    params, never one per call. CPU engines build none (their device memory
+    is host memory), so both stay 0 there.
     """
 
     requests: int = 0
@@ -237,6 +251,8 @@ class ServeStats:
     ctx_tail_fields: int = 0
     host_arg_bytes: int = 0
     slots_scored: int = 0
+    table_uploads: int = 0
+    table_upload_bytes: int = 0
     phase_s: Dict[str, float] = field(default_factory=dict)
     # fault-tolerance counters (PR 9) — populated by the ShardRouter:
     degraded_responses: int = 0  # responses with >=1 zero-rows slice
@@ -281,6 +297,8 @@ class ServeStats:
         self.ctx_tail_fields += other.ctx_tail_fields
         self.host_arg_bytes += other.host_arg_bytes
         self.slots_scored += other.slots_scored
+        self.table_uploads += other.table_uploads
+        self.table_upload_bytes += other.table_upload_bytes
         self.add_phases(other.phase_s)
         self.degraded_responses += other.degraded_responses
         self.deadline_misses += other.deadline_misses
@@ -362,6 +380,24 @@ def host_arg_nbytes(args) -> int:
     device counts 0."""
     return sum(x.nbytes for x in jax.tree_util.tree_leaves(args)
                if isinstance(x, (np.ndarray, np.generic)))
+
+
+# backends whose device memory is host memory: a device-resident copy of the
+# gather tables there would only double host RAM
+_HOST_MEMORY_BACKENDS = ("cpu",)
+
+
+def _tables_resident(host_gather: bool, params) -> bool:
+    """Whether an engine keeps a device-resident twin of ``params`` (see
+    :meth:`InferenceEngine._device_params`): only where its forward gathers
+    in-trace (the tables are then arguments of every forward call), the
+    backend's device memory is not host memory, and the tables are arrays
+    rather than sharded views that gather on host (``gather_np``)."""
+    if (host_gather or params is None
+            or jax.default_backend() in _HOST_MEMORY_BACKENDS):
+        return False
+    return not any(hasattr(t, "gather_np")
+                   for t in (params["ffm"]["emb"], params["lr"]["w"]))
 
 
 # ---------------------------------------------------------------------------
@@ -872,6 +908,7 @@ class InferenceEngine:
         # time.monotonic() budget, thread-local because concurrent scorer
         # threads carry independent budgets through the same engine
         self._deadline_tl = threading.local()
+        self._device_params(self.params)  # upload before the first request
         if warmup_buckets is not None and params is not None:
             self.warmup(max_requests=warmup_buckets[0],
                         max_candidates=warmup_buckets[1])
@@ -964,15 +1001,18 @@ class InferenceEngine:
         quantized engine f32 params are row-quantized here (full-table —
         only the update pipe knows touched rows)."""
         params = self._maybe_quantize(params)
+        self._device_params(params)  # upload before the swap
         with self._lock:  # serialize the generation bump against _publish
             self._weights = (params, self._weights[1] + 1)
 
     def _publish(self, params, version: int, nbytes: int) -> int:
         """Atomically install a fully materialized params pytree (the update
         pipe's publish step — the only weight work under the request lock).
-        The quantize fallback runs *before* the lock and is a no-op for the
-        update pipe, which ships already-quantized tables."""
+        The quantize fallback and the device twin's upload run *before* the
+        lock (the quantize is a no-op for the update pipe, which ships
+        already-quantized tables), on the pipe's thread."""
         params = self._maybe_quantize(params)
+        self._device_params(params)
         with self._lock:
             self._weights = (params, self._weights[1] + 1)
             self.weights_version = version
@@ -1083,6 +1123,39 @@ class InferenceEngine:
         lr = host_view(params["lr"]["w"])
         self._host_tables = ((params, emb, lr),) + self._host_tables[:1]
         return emb, lr
+
+    _device_tables: Tuple = ()  # up to 2 of (params, device twin)
+
+    def _device_params(self, params):
+        """``params`` with every numpy leaf copied to the device — the twin
+        the in-trace forward (:func:`batched_candidates_forward`) takes, so
+        the gather tables cross to the device once per published generation
+        instead of inside every forward call. Non-array leaves (the LR
+        table's Python-int ``block``) stay as they are. Returns ``params``
+        itself where :func:`_tables_resident` says no twin is kept (host
+        pre-gather, CPU backend, sharded views).
+
+        Cached per params object in two slots, like :meth:`_host_weights`:
+        the published generation and the one being published. Construction,
+        :meth:`install_params` and :meth:`_publish` fill the slot before the
+        swap and :meth:`rotate` hands its twin to the successor, so the
+        request path only finds; a miss there uploads too. Every upload
+        counts in ``ServeStats.table_uploads`` / ``table_upload_bytes``."""
+        for entry in self._device_tables:
+            if entry[0] is params:
+                return entry[1]
+        if not _tables_resident(self.host_gather, params):
+            return params
+        nbytes = host_arg_nbytes(params)
+        twin = jax.block_until_ready(jax.tree_util.tree_map(
+            lambda x: (jax.device_put(x)
+                       if isinstance(x, (np.ndarray, np.generic)) else x),
+            params))
+        self._device_tables = ((params, twin),) + self._device_tables[:1]
+        with self._lock:
+            self.stats.table_uploads += 1
+            self.stats.table_upload_bytes += nbytes
+        return twin
 
     def _head_params(self, params):
         """``params`` minus the resident gather tables — what the pre-gather
@@ -1652,7 +1725,9 @@ class InferenceEngine:
         (:meth:`_compact_grids`); ``None`` falls back to the per-padded-row
         table gather (warmup dummies, ``score_uncached``). ``out_codes`` is
         an optional caller-provided destination for the packed code/row
-        gather — the scoring pool's recycled double buffer."""
+        gather — the scoring pool's recycled double buffer. The in-trace
+        forward takes the whole tables as arguments: their device twin
+        (:meth:`_device_params`) where the engine keeps one."""
         emb = params["ffm"]["emb"]
         if self.host_gather:
             from repro.kernels.row_gather import ops as rg_ops
@@ -1691,7 +1766,8 @@ class InferenceEngine:
                     self._head_params(params), stacked,
                     ec.astype(np.float32, copy=False), kv_b, lr_cand)
         return batched_candidates_forward, (
-            self.cfg, self.model, self.backend, params, stacked, ki_b, kv_b)
+            self.cfg, self.model, self.backend, self._device_params(params),
+            stacked, ki_b, kv_b)
 
     def _candidates_forward(self, params, stacked, ki_b, kv_b, grids=None):
         """Route one padded candidate block through the right jitted forward
@@ -1758,7 +1834,9 @@ class InferenceEngine:
         calls = 0
         # numpy dummies, matching the hot path: jax's jit cache keys on the
         # argument container type, so warming with device arrays would leave
-        # the numpy-argument entries cold. On a fused engine the dummies are
+        # the numpy-argument entries cold. The tables go through
+        # _forward_args as on the hot path, so an engine that keeps a device
+        # twin warms the twin's entries. On a fused engine the dummies are
         # fused context states (depth/pair_sum instead of the pair vector) —
         # the fused forward's compiled shape set is covered the same way.
         for rb in rbs:
@@ -1807,8 +1885,12 @@ class InferenceEngine:
         # never see it move backwards. The successor is still private, but
         # it gets published to other threads later — write under its lock
         # so the adoption happens-before any post-publish read.
+        params, generation = self._weights
         with succ._lock:
-            succ._weights = (self.params, self.generation)
+            succ._weights = (params, generation)
+        # the device twin too: the successor uploads nothing
+        succ._device_tables = tuple(e for e in self._device_tables
+                                    if e[0] is params)
         buckets = warmup_buckets or self._warmed_buckets
         if buckets is not None:
             succ.warmup(max_requests=buckets[0], max_candidates=buckets[1])
