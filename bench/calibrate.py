@@ -7,8 +7,10 @@ process, so the engine's programs compile (or load from the cache) once.
     python3 bench/calibrate.py sweep --workload <cell> --rates 40,80,160 \\
         --seconds 8 --seed <n>
         Offers the cell's open-loop mix at each rate and prints, per rate,
-        the completion rate, p50/p99 latency, and whether latency grew over
-        the window (a backlog): the highest rate without one is the knee.
+        the completion rate, p50/p90/p95/p99 latency, and whether latency
+        grew over the window (a backlog). The knee is the highest rate that
+        keeps pace (no backlog, completions match the offer) with its median
+        under 100 ms; past it calls batch up and the median jumps.
 
     python3 bench/calibrate.py readings --workload <cell> --seeds 1,2,3 \\
         --seconds 4
@@ -61,6 +63,8 @@ def sweep(args) -> None:
         row = {"rate_per_s": rate, "requests": len(lat),
                "completed_per_s": len(lat) / span,
                "p50_ms": float(np.percentile(lat, 50)) * 1e3,
+               "p90_ms": float(np.percentile(lat, 90)) * 1e3,
+               "p95_ms": float(np.percentile(lat, 95)) * 1e3,
                "p99_ms": float(np.percentile(lat, 99)) * 1e3,
                "first_quarter_p50_ms": float(np.median(lat[:q])) * 1e3,
                "last_quarter_p50_ms": float(np.median(lat[-q:])) * 1e3,
@@ -74,15 +78,13 @@ def readings(args) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench.lib import weights
-
     seeds = [int(s) for s in args.seeds.split(",")]
     harness, spec, cell, cfg, mix, engine = _setup(args.workload, seeds[0])
-    reference = spec.reference(cfg)
+    family, reference = spec.family(cfg), spec.reference(cfg)
     prog, ctrl = [], []
     for i, seed in enumerate(seeds):
         if i:
-            engine.install_params(weights.engine_params(cfg, seed))
+            engine.install_params(family.engine_params(cfg, seed))
         run = harness.window(cell, cfg, mix, engine, seed, args.seconds)
         ids = harness.sample_requests(run, seed,
                                       int(cfg["checks"]["sample_requests"]))

@@ -17,7 +17,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from bench.lib import layout as layout_mod
-from bench.lib import spec, traffic, weights
+from bench.lib import spec, traffic
 
 
 @dataclass
@@ -185,11 +185,12 @@ TRACE_SECONDS = 10.0
 
 
 class WindowTrace:
-    """The profiler over the first ``TRACE_SECONDS`` of the window: a
-    traced staged call moves gigabytes to the device, and the profiler's
-    cost grows with the length it records. ``tick`` after each call stops
-    it once that time has passed; the ``bench.window`` span marks the
-    traced part for ``bench.lib.trace``."""
+    """The profiler over the whole window. The ``bench.window`` span marks
+    its first ``TRACE_SECONDS`` for ``bench.lib.trace``, which reads only
+    that part: ``tick`` after each call ends the span once that time has
+    passed. The profiler stops in ``close``, after the window: stopping it
+    takes seconds of host work, which inside the window would stall every
+    request due meanwhile."""
 
     def __init__(self, run: Run, trace_dir: str):
         from bench.lib.trace import profile_options
@@ -207,17 +208,20 @@ class WindowTrace:
 
     def tick(self) -> None:
         if self.span is not None and time.perf_counter() >= self.until:
-            self.close()
+            self.end_span()
 
-    def close(self) -> None:
-        import jax
-
+    def end_span(self) -> None:
         if self.span is None:
             return
         self.span.__exit__(None, None, None)
         self.span = None
-        jax.profiler.stop_trace()
         self.run.traced_calls = len(self.run.calls)
+
+    def close(self) -> None:
+        import jax
+
+        self.end_span()
+        jax.profiler.stop_trace()
 
 
 class CompileCounter:
@@ -306,18 +310,6 @@ def passed(checks: dict) -> bool:
             >= c["compared_predictions"]["limit"])
 
 
-def build_engine(cfg: dict, params):
-    from repro.common.config import FFMConfig
-    from repro.serving.engine import InferenceEngine
-
-    sizes = {key: cfg[key] for key in
-             ("n_fields", "context_fields", "hash_space", "k")}
-    if "mlp_hidden" in cfg:  # the deepffm head's MLP
-        sizes["mlp_hidden"] = tuple(cfg["mlp_hidden"])
-    fcfg = FFMConfig(**sizes)
-    return InferenceEngine(fcfg, cfg["head"], params=params, **cfg["engine"])
-
-
 def _peak_bytes() -> int:
     import jax
 
@@ -326,14 +318,16 @@ def _peak_bytes() -> int:
 
 
 def prepare(cfg: dict, mix: dict, seed: int):
-    """Tables from the seed, the engine over them, and a warmup of every
-    bucket the mix can emit. Returns ``(engine, seconds per step)``."""
+    """Tables from the seed, the engine over them (both made by the
+    configuration's family), and a warmup of every bucket the mix can emit.
+    Returns ``(engine, seconds per step)``."""
+    family = spec.family(cfg)
     split = {}
     t = time.perf_counter()
-    params = weights.engine_params(cfg, seed)
+    params = family.engine_params(cfg, seed)
     split["tables_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    engine = build_engine(cfg, params)
+    engine = family.build_engine(cfg, params)
     rows = -(-int(mix["max_batch"]) // engine.parallel)
     engine.warmup(max_requests=rows, max_candidates=mix["candidates"]["max"])
     split["warmup_s"] = time.perf_counter() - t
@@ -344,8 +338,7 @@ def window(cell: dict, cfg: dict, mix: dict, engine, seed: int,
            seconds: float, *, score=None, trace_dir: Optional[str] = None,
            t_process: Optional[float] = None) -> Run:
     """Generate the seed's traffic and run the mix's loop for ``seconds``
-    (its first ``TRACE_SECONDS`` under the profiler when ``trace_dir`` is
-    given)."""
+    (under the profiler when ``trace_dir`` is given)."""
     t = time.perf_counter()
     reqs = traffic.generate(mix, cfg, seed,
                             traffic.request_count(mix, seconds))

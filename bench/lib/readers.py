@@ -16,13 +16,14 @@ def _ok_calls(run):
 
 # -- end to end (host clock) -------------------------------------------------
 
-def p99_ms(run):
-    """99th percentile over all requests of the window, each timed from its
-    due time in the open-loop schedule to its scores back on the host."""
+def latency_ms(run, q: float):
+    """The ``q``-th percentile over all requests of the window, each timed
+    from its due time in the open-loop schedule to its scores back on the
+    host; a request never answered counts as infinitely late."""
     if run.mix["loop"] != "open" or not len(run.reqs):
         return None
     lat = np.where(run.done > 0, run.done - run.due_abs(), np.inf)
-    return float(np.percentile(lat, 99)) * 1e3
+    return float(np.percentile(lat, q)) * 1e3
 
 
 def preds_per_s(run):

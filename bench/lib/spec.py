@@ -1,6 +1,9 @@
 """``BENCHMARK.json`` and the files its names lead to.
 
   configuration ``c``   the file the entry names (``bench/configs/c.json``)
+  its model family      ``bench/families/<reference>.py``: ``engine_params``,
+                        ``build_engine``, ``flops_per_prediction`` and the
+                        seeded rows and head its reference reads
   its reference         ``bench/references/<reference>.py``
   traffic mix ``t``     ``bench/traffic/t.json`` (``bench.lib.traffic``)
   metric ``m``          ``bench/metrics/m.py``, whose ``read(run)`` returns
@@ -15,7 +18,6 @@ from __future__ import annotations
 
 import functools
 import glob
-import importlib
 import importlib.util
 import json
 import os
@@ -23,6 +25,8 @@ from typing import Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+FAMILIES_DIR = os.path.join(BENCH_DIR, "families")
+REFERENCES_DIR = os.path.join(BENCH_DIR, "references")
 
 
 def load(path: Optional[str] = None) -> dict:
@@ -47,27 +51,32 @@ def config(bench: dict, name: str) -> dict:
         return json.load(f)
 
 
-def reference(cfg: dict):
-    return importlib.import_module(f"bench.references.{cfg['reference']}")
-
-
-def _load_file(path: str, modname: str):
-    s = importlib.util.spec_from_file_location(modname, path)
+@functools.lru_cache(maxsize=None)
+def _module(directory: str, name: str, kind: str):
+    """``<directory>/<name>.py``, loaded once."""
+    s = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_"),
+        os.path.join(directory, f"{name}.py"))
     mod = importlib.util.module_from_spec(s)
     s.loader.exec_module(mod)
     return mod
 
 
-@functools.lru_cache(maxsize=None)
+def family(cfg: dict):
+    """The configuration's model family: everything head-specific."""
+    return _module(FAMILIES_DIR, cfg["reference"], "family")
+
+
+def reference(cfg: dict):
+    return _module(REFERENCES_DIR, cfg["reference"], "reference")
+
+
 def metric_reader(name: str):
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    return _load_file(path, "bench_metric_" + name.replace(".", "_"))
+    return _module(os.path.join(BENCH_DIR, "metrics"), name, "metric")
 
 
-@functools.lru_cache(maxsize=None)
 def kernel(name: str):
-    return _load_file(os.path.join(BENCH_DIR, "flops", f"{name}.py"),
-                      "bench_flops_" + name)
+    return _module(os.path.join(BENCH_DIR, "flops"), name, "flops")
 
 
 def kernel_names():

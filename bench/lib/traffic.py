@@ -26,9 +26,8 @@ parameters it extends. The keys:
                   other fields are categorical, value 1
 
 Every seed gets the same amount of work: the same number of requests, the
-same multiset of slate lengths and of prefix depths, and arrival times
-spread the same way (a Poisson process conditioned on its count); the seed
-chooses their order and the contents.
+same multiset of slate lengths, of prefix depths and of gaps between
+arrivals; the seed chooses their order and the contents.
 """
 from __future__ import annotations
 
@@ -85,6 +84,17 @@ def slate_lengths(n: int, c: dict) -> np.ndarray:
     z = np.asarray([NormalDist().inv_cdf((i + 0.5) / n) for i in range(n)])
     x = np.exp(np.log(c["median"]) + c["sigma"] * z)
     return np.clip(np.rint(x), c["min"], c["max"]).astype(np.int64)
+
+
+def arrival_times(n: int, rate: float, rng) -> np.ndarray:
+    """Due times of ``n`` open-loop arrivals at ``rate`` per second: the
+    gaps are the exponential's quantiles (i + 0.5) / n, scaled to span
+    ``n / rate`` seconds, in the seed's order. Poisson-like bursts, but the
+    same multiset of gaps for every seed."""
+    gaps = -np.log1p(-(np.arange(n) + 0.5) / n)
+    gaps *= (n / rate) / gaps.sum()
+    rng.shuffle(gaps)
+    return np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
 
 
 def exact_counts(n: int, shares: dict) -> dict:
@@ -190,7 +200,7 @@ def generate(mix: dict, model: dict, seed: int, n: int) -> Requests:
 
     due = np.zeros(n)
     if mix["loop"] == "open":
-        due = np.sort(rng.random(n)) * (n / float(mix["rate_per_s"]))
+        due = arrival_times(n, float(mix["rate_per_s"]), rng)
     return Requests(ctx_idx.astype(np.int32), ctx_val.astype(np.float32),
                     depth, s_of, offsets, ads, ad_idx, ad_val, due)
 

@@ -1,4 +1,6 @@
-"""Seeded int8 tables and f32 head weights, made without the program.
+"""Seeded int8 tables and f32 head tensors, made without the program: what
+every model family shares (``bench/families/<name>.py`` builds its params
+from these).
 
 Every value is a function of ``(seed, stream, counter)`` through a 32-bit
 integer hash, so the whole table is made on the device by one jitted
@@ -7,10 +9,10 @@ seed alone.
 Every value is exactly representable in float32 (small integers times powers
 of two), so no order of computation or fusion can change a single bit.
 
-Layout is the serving engine's int8 form (paper §6): embedding rows
-``codes (V, F, k) int8`` with a per-row ``(scale, zero)``; the LR vector as
-int8 codes with one ``(scale, zero)`` per block of 64; the head (LR bias,
-MergeNorm, MLP) in float32.
+Layout is the serving engine's int8 form (paper §6): ``V`` embedding rows of
+``width`` int8 codes, each with a float32 ``(scale, zero)``; the LR vector as
+int8 codes with one ``(scale, zero)`` per block of 64. A family's head
+tensors are float32 (``head_tensor``).
 """
 from __future__ import annotations
 
@@ -21,14 +23,11 @@ import numpy as np
 LR_BLOCK = 64
 CHUNK_ROWS = 2 ** 15   # table rows made per device call
 
-# the last MLP layer's scale on top of its fan-in scale: the MLP adds a
-# residual of a trained model's size to the wide logit
-MLP_OUT_SCALE = 0.25
-
-# one stream id per leaf, so no two leaves share a counter space
+# One stream id per leaf, so no two leaves share a counter space. These are
+# the tables'; a family's head tensors take ids of their own, outside these
+# and outside every other family's (the FFM family holds 7-9 and 16 on).
 STREAMS = {"emb_codes": 1, "emb_scale": 2, "emb_zero": 3, "lr_codes": 4,
-           "lr_scale": 5, "lr_zero": 6, "lr_b": 7, "merge_scale": 8,
-           "merge_bias": 9, "mlp": 16}
+           "lr_scale": 5, "lr_zero": 6}
 
 
 def _mix(x, xp):
@@ -93,63 +92,34 @@ def signed_unit(h, xp):
     return ((h >> xp.uint32(16)).astype(xp.float32) - 32768.0) * (2.0 ** -15)
 
 
-# -- the head (small: made on the host, identical for program and reference) --
+# -- head tensors (small: made on the host, the same for program and reference)
 
-def mlp_dims(cfg: dict):
-    d_in = cfg["n_fields"] * (cfg["n_fields"] - 1) // 2 + 1
-    return (d_in,) + tuple(cfg["mlp_hidden"]) + (1,)
-
-
-def head_params(cfg: dict, seed: int) -> dict:
-    """LR bias and, for the deepffm head, MergeNorm and MLP weights.
-
-    MLP layer i is uniform with a fan-in scale (a power of two near
-    ``1/sqrt(fan_in)``); the last layer is further scaled by ``MLP_OUT_SCALE``.
-    """
+def head_tensor(seed: int, stream: int, shape, scale: float,
+                offset: float = 0.0) -> np.ndarray:
+    """A small float32 head tensor of the seed, made on the host: uniform in
+    ``offset + scale * [-1, 1)``, so program and reference hold the same
+    bits."""
     lo, hi = seed_words(seed)
-
-    def leaf(stream, shape, scale, offset=0.0):
-        n = int(np.prod(shape)) if shape else 1
-        h = bits(lo, hi, stream, np.arange(n, dtype=np.uint32), np)
-        v = signed_unit(h, np) * np.float32(scale) + np.float32(offset)
-        return v.reshape(shape).astype(np.float32)
-
-    out = {"lr_b": leaf(STREAMS["lr_b"], (), 2.0 ** -3)}
-    if cfg["head"] != "deepffm":
-        return out
-    dims = mlp_dims(cfg)
-    out["merge_scale"] = leaf(STREAMS["merge_scale"], (dims[0],), 2.0 ** -3,
-                              1.0)
-    out["merge_bias"] = leaf(STREAMS["merge_bias"], (dims[0],), 2.0 ** -3)
-    mlp = {}
-    for i in range(len(dims) - 1):
-        fan = 2.0 ** -round(np.log2(np.sqrt(dims[i])))
-        if i == len(dims) - 2:
-            fan *= MLP_OUT_SCALE
-        mlp[f"w{i}"] = leaf(STREAMS["mlp"] + 2 * i, (dims[i], dims[i + 1]),
-                            fan)
-        mlp[f"b{i}"] = leaf(STREAMS["mlp"] + 2 * i + 1, (dims[i + 1],),
-                            2.0 ** -4)
-    out["mlp"] = mlp
-    return out
+    n = int(np.prod(shape)) if shape else 1
+    h = bits(lo, hi, stream, np.arange(n, dtype=np.uint32), np)
+    v = signed_unit(h, np) * np.float32(scale) + np.float32(offset)
+    return v.reshape(shape).astype(np.float32)
 
 
 # -- the tables ---------------------------------------------------------------
 
-def table_rows(cfg: dict, seed: int, rows, xp=np):
-    """Dequantized embedding rows ``(len(rows), F, k)`` and LR weights
-    ``(len(rows),)`` of the seed's tables, in float32: what the reference
+def table_rows(seed: int, rows, width: int, xp=np):
+    """Dequantized embedding rows ``(len(rows), width)`` and LR weights
+    ``(len(rows),)`` of the seed's tables, in float32: what a reference
     gathers. ``rows`` holds hashed feature indices."""
     lo, hi = seed_words(seed)
-    f, k = cfg["n_fields"], cfg["k"]
     rows = xp.asarray(rows).astype(xp.uint32)
-    cnt = (rows[:, None] * xp.uint32(f * k)
-           + xp.arange(f * k, dtype=xp.uint32)[None, :])
+    cnt = (rows[:, None] * xp.uint32(width)
+           + xp.arange(width, dtype=xp.uint32)[None, :])
     codes = codes_of(bits(lo, hi, STREAMS["emb_codes"], cnt, xp), xp)
     scale = emb_scale_of(bits(lo, hi, STREAMS["emb_scale"], rows, xp), xp)
     zero = emb_zero_of(bits(lo, hi, STREAMS["emb_zero"], rows, xp), xp)
-    emb = (codes.astype(xp.float32).reshape(-1, f, k) * scale[:, None, None]
-           + zero[:, None, None])
+    emb = codes.astype(xp.float32) * scale[:, None] + zero[:, None]
     blk = rows // xp.uint32(LR_BLOCK)
     lr_code = codes_of(bits(lo, hi, STREAMS["lr_codes"], rows, xp), xp)
     lr = (lr_code.astype(xp.float32)
@@ -159,7 +129,7 @@ def table_rows(cfg: dict, seed: int, rows, xp=np):
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk_program(f: int, k: int, c: int):
+def _chunk_program(width: int, c: int):
     """The jitted maker of ``c`` table rows from ``row0`` on. The seed and
     ``row0`` are traced arguments, so every seed and chunk reuses one
     compiled program."""
@@ -169,8 +139,8 @@ def _chunk_program(f: int, k: int, c: int):
     @jax.jit
     def make(lo, hi, row0):
         rows = row0 + jnp.arange(c, dtype=jnp.uint32)
-        cnt = rows[:, None] * jnp.uint32(f * k) \
-            + jnp.arange(f * k, dtype=jnp.uint32)[None, :]
+        cnt = rows[:, None] * jnp.uint32(width) \
+            + jnp.arange(width, dtype=jnp.uint32)[None, :]
         blk = row0 // jnp.uint32(LR_BLOCK) \
             + jnp.arange(c // LR_BLOCK, dtype=jnp.uint32)
         return {
@@ -192,19 +162,20 @@ def _chunk_program(f: int, k: int, c: int):
     return make
 
 
-def make_tables(cfg: dict, seed: int) -> dict:
-    """The whole int8 table set as host numpy, made on the default device
-    ``CHUNK_ROWS`` rows per call and copied out as it comes: the device
-    holds two chunks at a time (one being made, one being copied), so the
-    set-up leaves no table-sized peak in the device's memory. The embedding
-    codes come out flat (``V * F * k``)."""
+def make_tables(seed: int, hash_space: int, width: int) -> dict:
+    """The whole int8 table set of ``hash_space`` rows of ``width`` codes as
+    host numpy, made on the default device ``CHUNK_ROWS`` rows per call and
+    copied out as it comes: the device holds two chunks at a time (one being
+    made, one being copied), so the set-up leaves no table-sized peak in the
+    device's memory. The embedding codes come out flat
+    (``hash_space * width``)."""
     import jax.numpy as jnp
 
-    v, f, k = cfg["hash_space"], cfg["n_fields"], cfg["k"]
+    v = hash_space
     n_blk = -(-v // LR_BLOCK)
     c = min(CHUNK_ROWS, n_blk * LR_BLOCK)
-    make = _chunk_program(f, k, c)
-    out = {"codes": np.empty(v * f * k, np.int8),
+    make = _chunk_program(width, c)
+    out = {"codes": np.empty(v * width, np.int8),
            "scale": np.empty(v, np.float32), "zero": np.empty(v, np.float32),
            "lr_codes": np.empty(v, np.int8),
            "lr_scale": np.empty(n_blk, np.float32),
@@ -216,7 +187,7 @@ def make_tables(cfg: dict, seed: int) -> dict:
         for key, a in chunk.items():
             a = np.asarray(a)
             if key == "codes":
-                out[key][row0 * f * k:(row0 + n) * f * k] = a[:n * f * k]
+                out[key][row0 * width:(row0 + n) * width] = a[:n * width]
             elif key in ("lr_scale", "lr_zero"):
                 out[key][b0:b0 + nb] = a[:nb]
             else:
@@ -231,26 +202,3 @@ def make_tables(cfg: dict, seed: int) -> dict:
         pending = nxt
     store(*pending)
     return out
-
-
-def engine_params(cfg: dict, seed: int) -> dict:
-    """The serving engine's params pytree for the seed: int8 tables made on
-    the device and held as host numpy arrays, the form the engine's update
-    pipe publishes (so every scoring call passes them as it would in
-    service), plus the float32 head."""
-    t = make_tables(cfg, seed)
-    t["codes"] = t["codes"].reshape(cfg["hash_space"], cfg["n_fields"],
-                                    cfg["k"])
-    head = head_params(cfg, seed)
-    params = {
-        "lr": {"w": {"codes": t["lr_codes"], "scale": t["lr_scale"],
-                     "zero": t["lr_zero"], "block": LR_BLOCK},
-               "b": head["lr_b"]},
-        "ffm": {"emb": {"codes": t["codes"], "scale": t["scale"],
-                        "zero": t["zero"]}},
-    }
-    if cfg["head"] == "deepffm":
-        params["merge_scale"] = head["merge_scale"]
-        params["merge_bias"] = head["merge_bias"]
-        params["mlp"] = head["mlp"]
-    return params

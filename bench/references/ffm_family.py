@@ -12,8 +12,9 @@ and its logit is
 with ``MergeNorm(z) = (z - mean(z)) / sqrt(var(z) + 1e-6) * scale + bias``
 and a ReLU MLP (arXiv:2407.10115 §2.1; the additive shortcut is how the
 paper's engine composes its blocks). ``E`` and ``w`` are the dequantized
-int8 tables the seed defines (``bench.lib.weights.table_rows``), so the
-reference reads nothing the program made.
+int8 tables the seed defines (``table_rows`` of the FFM family,
+``bench/families/ffm_family.py``), so the reference reads nothing the
+program made.
 
 ``dtype=float32`` runs at the highest matmul precision: the yardstick.
 ``dtype=bfloat16`` computes every product and sum in bfloat16: the control,
@@ -27,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib.weights import head_params, table_rows
+from bench.lib import spec
 
 
 def _pairs(n_fields: int):
@@ -75,7 +76,8 @@ def logits(cfg: dict, seed: int, idx: np.ndarray, val: np.ndarray, *,
     """Reference logits of full feature rows ``idx``/``val`` (B, F), computed
     ``block`` rows per call (rows padded to a whole block, so one program
     serves every sample)."""
-    hp = head_params(cfg, seed)
+    family = spec.family(cfg)
+    hp = family.head_params(cfg, seed)
     out = []
     for s in range(0, len(idx), block):
         bi, bv = idx[s:s + block], val[s:s + block]
@@ -87,7 +89,7 @@ def logits(cfg: dict, seed: int, idx: np.ndarray, val: np.ndarray, *,
         # a fixed number of unique-row slots keeps the program's shape fixed
         pad = block * cfg["n_fields"] - rows.size
         rows = np.concatenate([rows, np.zeros(pad, rows.dtype)])
-        emb_u, lr_u = table_rows(cfg, seed, jnp.asarray(rows), jnp)
+        emb_u, lr_u = family.table_rows(cfg, seed, jnp.asarray(rows), jnp)
         got = _logits(cfg["head"], cfg["n_fields"], cfg["k"], dtype, emb_u,
                       lr_u, jnp.asarray(inv.reshape(bi.shape), jnp.int32),
                       jnp.asarray(bv, jnp.float32), hp)

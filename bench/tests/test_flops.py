@@ -2,29 +2,32 @@
 the call layout against the engine's own counter."""
 import numpy as np
 
-from bench.lib import layout, modelflops, spec
+from bench.lib import layout, spec
 
-TINY = {"n_fields": 4, "context_fields": 2, "k": 2, "mlp_hidden": [3]}
+TINY = {"n_fields": 4, "context_fields": 2, "k": 2, "mlp_hidden": [3],
+        "reference": "ffm_family"}
+FFM = spec.family(TINY)
 
 
 def test_model_flops_hand_count():
     # F=4, k=2: P=6 pairs. LR 2*4 = 8; pair dots 6*(2*2) = 24 plus two
     # value products each, 12; head sum 6: ffm = 50.
-    assert modelflops.per_prediction({**TINY, "head": "ffm"}) == 50
+    assert FFM.flops_per_prediction({**TINY, "head": "ffm"}) == 50
     # deepffm adds MergeNorm over d=7 (7*7 + 1 = 50), the MLP 7->3->1
     # (2*7*3 + 3 = 45, 2*3*1 + 1 = 7) and the shortcut add (1): 153.
-    assert modelflops.per_prediction({**TINY, "head": "deepffm"}) == 153
+    assert FFM.flops_per_prediction({**TINY, "head": "deepffm"}) == 153
 
 
 def test_config_flops_match_their_sizes():
     bench = spec.load()
     for c in bench["configs"]:
         cfg = spec.config(bench, c["name"])
-        assert cfg["flops_per_prediction"] == modelflops.per_prediction(cfg)
+        family = spec.family(cfg)
+        assert cfg["flops_per_prediction"] == family.flops_per_prediction(cfg)
     # PROD_FFM widths: F=24, k=8, P=276, MLP 277->64->32->1
-    assert modelflops.per_prediction(
+    assert FFM.flops_per_prediction(
         {"n_fields": 24, "k": 8, "head": "ffm", "mlp_hidden": []}) == 5292
-    assert modelflops.per_prediction(
+    assert FFM.flops_per_prediction(
         {"n_fields": 24, "k": 8, "head": "deepffm",
          "mlp_hidden": [64, 32]}) == 46946
 
@@ -65,10 +68,8 @@ def test_bucket_and_spans():
 def test_layout_matches_the_engine():
     import jax
 
-    from bench.lib import harness, weights
-
     cfg = {"n_fields": 24, "context_fields": 16, "k": 8, "hash_space": 4096,
-           "head": "ffm",
+           "head": "ffm", "reference": "ffm_family",
            "engine": {"backend": "reference", "quantized": True}}
     rng = np.random.default_rng(0)
     ctx = [(rng.integers(0, 4096, 16).astype(np.int32),
@@ -79,9 +80,9 @@ def test_layout_matches_the_engine():
         ci, cv = ctx[i % 3]      # 0 and 3, 1 and 4 share a context
         a = ads[rng.integers(0, 40, n)]  # repeated ads within and across
         reqs.append((ci, cv, a, np.ones_like(a, np.float32)))
+    family = spec.family(cfg)
     for workers in (1, 4):
-        eng = harness.build_engine(
-            cfg, weights.engine_params(cfg, 3))
+        eng = family.build_engine(cfg, family.engine_params(cfg, 3))
         eng.parallel = workers
         before = eng.stats.rows_scored
         eng.score_batch(reqs)

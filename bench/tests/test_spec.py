@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from bench.lib import modelflops, spec, traffic
+from bench.lib import spec, traffic
 
 BENCH = spec.load()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -35,7 +35,10 @@ def test_configs_resolve():
         assert c["file"].startswith(BENCH["paths"][0] + "/")
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert spec.reference(cfg).logits
-        assert cfg["flops_per_prediction"] == modelflops.per_prediction(cfg)
+        family = spec.family(cfg)
+        assert callable(family.engine_params)
+        assert callable(family.build_engine)
+        assert cfg["flops_per_prediction"] == family.flops_per_prediction(cfg)
         assert any(w["config"] == c["name"] for w in BENCH["workloads"])
 
 

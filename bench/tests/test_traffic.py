@@ -99,8 +99,15 @@ def test_open_loop_schedule():
     r = traffic.generate(mix, MODEL, 9, n)
     assert np.all(np.diff(r.due_s) >= 0)
     assert 0 <= r.due_s[0] and r.due_s[-1] <= n / mix["rate_per_s"]
-    # Poisson arrivals conditioned on their count: uniform over the window
+    # exponential gaps: arrivals spread evenly over the window at large
     assert np.mean(r.due_s < 5.0) == pytest.approx(0.5, abs=0.05)
+    # every seed gets the same gaps (the last one up to n / rate), in its
+    # own order
+    other = traffic.generate(mix, MODEL, 2 ** 32 + 11, n)
+    span = n / mix["rate_per_s"]
+    gaps = [np.sort(np.diff(np.append(x.due_s, span))) for x in (r, other)]
+    np.testing.assert_allclose(gaps[0], gaps[1], rtol=1e-9, atol=1e-12)
+    assert not np.allclose(other.due_s, r.due_s)
 
 
 def test_every_mix_file_loads():
