@@ -31,9 +31,10 @@ def _fit_online(model: str, n_batches: int = 300, batch: int = 512, lr: float = 
     lr = lr or LRS[model]
     stream = CTRStream(CFG, seed=seed, drift=0.001)
     if model == "dcnv2":
-        params = dcnv2.init_params(CFG, jax.random.PRNGKey(seed))
-        vg = jax.jit(jax.value_and_grad(lambda p, b: dcnv2.loss_fn(CFG, p, b)))
-        predict = jax.jit(lambda p, i, v: jax.nn.sigmoid(dcnv2.forward(CFG, p, i, v)))
+        dcn = CFG.replace(n_cross=3)
+        params = dcnv2.init_params(dcn, jax.random.PRNGKey(seed))
+        vg = jax.jit(jax.value_and_grad(lambda p, b: dcnv2.loss_fn(dcn, p, b)))
+        predict = jax.jit(lambda p, i, v: jax.nn.sigmoid(dcnv2.forward(dcn, p, i, v)))
     else:
         params = deepffm.init_params(CFG, jax.random.PRNGKey(seed), model)
         vg = jax.jit(jax.value_and_grad(

@@ -146,6 +146,10 @@ class FFMConfig:
     Mirrors Fwumious Wabbit: hashed feature space, per-field embeddings of
     width ``k``, LR part, and an MLP head over the merged+normalized LR/FFM
     outputs (paper eq. Dffm).
+
+    The DCN-v2 baseline (``repro.core.dcnv2``) reads the same fields: ``k``
+    is its per-field embedding width, ``mlp_hidden`` its deep widths and
+    ``n_cross`` its number of cross layers (0 for the FFM heads).
     """
 
     n_fields: int = 24
@@ -154,6 +158,7 @@ class FFMConfig:
     mlp_hidden: tuple = (64, 32)
     mlp_act: str = "relu"  # ReLU is what makes §4.3 sparse updates possible
     context_fields: int = 16  # first `context_fields` fields are the request context (§5)
+    n_cross: int = 0  # DCN-v2 cross layers; the FFM heads have none
     dtype: str = "float32"
     seed: int = 0
 

@@ -93,7 +93,8 @@ def param_specs(cfg: FFMConfig, model: str = "deepffm") -> Dict[str, Any]:
             "merge_bias": ParamSpec((d_merge,), ("null",), "zeros", dt),
             "mlp": _mlp_specs(cfg, d_merge),
         }
-    raise ValueError(model)
+    raise ValueError(f"no parameters for model {model!r} here: linear, mlp, "
+                     f"ffm and deepffm (DCN-v2's are repro.core.dcnv2's)")
 
 
 def init_params(cfg: FFMConfig, key, model: str = "deepffm"):

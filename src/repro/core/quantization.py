@@ -424,8 +424,9 @@ def quantize_params_rows(params, prev=None, touched_rows=None,
     """Serving-side quantize-on-ingest: replace the gather-table leaves of a
     params pytree with int8 quantized table dicts.
 
-    ``paths`` names the row-gathered tables (DeepFFM's ``ffm/emb`` and the
-    mlp baseline's top-level ``emb``) — per-row grids (:func:`quantize_rows`).
+    ``paths`` names the row-gathered tables (DeepFFM's ``ffm/emb``, and the
+    top-level ``emb`` of the mlp baseline and of DCN-v2, rows of width
+    ``k``) — per-row grids (:func:`quantize_rows`).
     ``block_paths`` names the scalar-per-row tables (the LR vector) — blocked
     grids (:func:`quantize_blocks`, block ``lr_block``). Every other leaf
     (MergeNorm, MLP, LR bias — tiny next to the tables) stays f32. ``prev``

@@ -107,6 +107,17 @@ composition point; each component maps to a paper section:
   forward composes per-shard partial sums in a fixed order — fusing inside
   shards would break the bit-invariance-across-shard-counts contract).
 
+**Model heads.** ``ffm`` and ``deepffm`` score through FFM pair terms;
+``dcnv2`` (:mod:`repro.core.dcnv2`) through the DCN-v2 cross stack. What
+differs between them is one :class:`Head` looked up by the model name: the
+gather tables, the context state the prefix trie holds (FFM prefix partials,
+or DCN-v2's value-scaled context rows ``x0``), its host tail arithmetic and
+the jitted staged forward. The trie, dedup, buckets, warmup, spans and the
+device twin are shared. The ``dcnv2`` forward computes the context's part
+of the first cross layer once per request row and the rest in the Pallas
+kernel ``dcn_cross_q8`` (``kernels/dcn_cross``); the update pipe, the shard
+router, the fused path and the host pre-gather refuse the head.
+
 **Parallel scoring (multi-core microbatch execution).** The paper's 300M+
 predictions/s saturates *every* core of a CPU box; a single-stream
 ``score_batch`` bounds one. ``InferenceEngine(parallel=N)`` splits each
@@ -200,7 +211,7 @@ from collections import Counter, deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -208,7 +219,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.common.config import FFMConfig
-from repro.core import deepffm, ffm
+from repro.core import dcnv2, deepffm, ffm
 from repro.core import quantization as Q
 from repro.serving.prefix_cache import (PrefixCache, context_from_tokens,
                                         context_tokens)
@@ -397,7 +408,7 @@ def _tables_resident(host_gather: bool, params) -> bool:
             or jax.default_backend() in _HOST_MEMORY_BACKENDS):
         return False
     return not any(hasattr(t, "gather_np")
-                   for t in (params["ffm"]["emb"], params["lr"]["w"]))
+                   for t in jax.tree_util.tree_leaves(params))
 
 
 # ---------------------------------------------------------------------------
@@ -801,6 +812,110 @@ def candidates_forward(cfg: FFMConfig, model: str, params, cached,
         jnp.asarray(cand_idx)[None], jnp.asarray(cand_val)[None])[0]
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def dcn_candidates_forward(cfg: FFMConfig, model: str, backend: str, params,
+                           cached, cand_idx, cand_val):
+    """The ``dcnv2`` head's staged forward (:func:`dcnv2.candidates_forward`)
+    under :func:`batched_candidates_forward`'s signature: ``params`` the
+    head's serving form (:func:`dcnv2.serving_params`), ``cached["x0"]``
+    (R, Fc, k) stacked context states, cand_idx/val (R, N, F-Fc) -> logits
+    (R, N). Its gathers are in-trace, from the resident twin on an
+    accelerator."""
+    return dcnv2.candidates_forward(cfg, params, cached["x0"], cand_idx,
+                                    cand_val)
+
+
+# ---------------------------------------------------------------------------
+# Model heads
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class Head:
+    """What differs between the engine's model heads. Everything else on
+    the scoring path is shared: the prefix trie, dedup, buckets, warmup,
+    spans, the device twin.
+
+    ``tables(params)`` is ``(emb, lr)``, the gather tables (``lr`` None for
+    a head without one). The host context state the prefix cache holds:
+    ``empty_state(cfg, params)``, ``extend_state(cfg, emb, lr, state, idx,
+    val)`` (the tail arithmetic, on host numpy views of the tables) and
+    ``slice_state(state, depth)``, which must be a pure slice;
+    ``dummy_state(cfg, params, rows)`` stacks zeros of it for warmup.
+    ``forward(cfg, model, backend, params, cached, cand_idx, cand_val)`` is
+    the jitted staged forward, which takes ``serving_params(cfg, backend,
+    params)``: what depends on the published params alone, built once per
+    params object (:meth:`InferenceEngine._device_params`), or ``params``
+    itself. ``oracle(cfg, params, idx, val, model, interactions_fn)`` is the
+    uncached forward (:meth:`InferenceEngine.score_uncached`). ``fleet``
+    says whether the update pipe, the shard router and the host pre-gather
+    take the head."""
+
+    tables: Callable
+    empty_state: Callable
+    extend_state: Callable
+    slice_state: Callable
+    dummy_state: Callable
+    forward: Callable
+    serving_params: Callable
+    oracle: Callable
+    fleet: bool
+
+
+def _ffm_dummy_state(cfg: FFMConfig, params, rb: int):
+    fc = cfg.context_fields
+    return {
+        "emb": np.zeros((rb, fc, cfg.n_fields, cfg.k),
+                        ffm.table_dtype(params["ffm"]["emb"])),
+        "val": np.zeros((rb, fc), np.float32),
+        "pairs": np.zeros((rb, ffm.prefix_pair_count(fc)), np.float32),
+        "lr_terms": np.zeros((rb, fc), np.float32),
+    }
+
+
+FFM_HEAD = Head(
+    tables=lambda params: (params["ffm"]["emb"], params["lr"]["w"]),
+    empty_state=lambda cfg, params: ffm.empty_context_prefix_np(
+        cfg, ffm.table_dtype(params["ffm"]["emb"])),
+    extend_state=ffm.extend_context_prefix_np,
+    slice_state=ffm.slice_context_prefix,
+    dummy_state=_ffm_dummy_state,
+    forward=batched_candidates_forward,
+    serving_params=lambda cfg, backend, params: params,
+    oracle=lambda cfg, params, idx, val, model, interactions_fn:
+        deepffm.forward(cfg, params, idx, val, model,
+                        interactions_fn=interactions_fn),
+    fleet=True)
+
+
+DCN_HEAD = Head(
+    tables=lambda params: (params["emb"], None),
+    empty_state=lambda cfg, params: dcnv2.empty_state_np(cfg),
+    extend_state=lambda cfg, emb, lr, state, idx, val:
+        dcnv2.extend_state_np(cfg, emb, state, idx, val),
+    slice_state=dcnv2.slice_state,
+    dummy_state=lambda cfg, params, rb: {
+        "x0": np.zeros((rb, cfg.context_fields, cfg.k), np.float32)},
+    forward=dcn_candidates_forward,
+    serving_params=dcnv2.serving_params,
+    oracle=lambda cfg, params, idx, val, model, interactions_fn:
+        dcnv2.forward(cfg, params, idx, val),
+    fleet=False)
+
+HEADS = {"dcnv2": DCN_HEAD}
+
+
+def head_of(model: str) -> Head:
+    """The head serving ``model``: ``dcnv2``, else the FFM family's."""
+    return HEADS.get(model, FFM_HEAD)
+
+
+def refuse_fleet(model: str, what: str) -> None:
+    """A clear error where ``what`` cannot serve ``model``'s head."""
+    if not head_of(model).fleet:
+        raise ValueError(f"{what} does not serve the {model!r} head: it "
+                         f"scores on one InferenceEngine's staged path only")
+
+
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
@@ -872,6 +987,13 @@ class InferenceEngine:
                  scoring_pool: Optional[ScoringPool] = None):
         from repro.kernels.row_gather import ops as rg_ops
 
+        self._head = head_of(model)
+        if not self._head.fleet:
+            if host_gather:
+                refuse_fleet(model, "the host pre-gather (host_gather=True)")
+            if cfg.n_cross < 1:
+                raise ValueError(f"the {model!r} head needs n_cross >= 1")
+            host_gather = False  # its rows are always gathered in-trace
         host_auto = host_gather is None
         resolved_host = (rg_ops.use_host_gather(cfg.hash_space)
                          if host_auto else bool(host_gather))
@@ -891,7 +1013,8 @@ class InferenceEngine:
             self._maybe_quantize(params), 0)
         self._cache = PrefixCache(  # guarded-by(calls): _lock
             cfg.context_fields, cache_entries,
-            stride=prefix_stride, depths=prefix_depths)
+            stride=prefix_stride, depths=prefix_depths,
+            slice_state=self._head.slice_state)
         self._lock = threading.Lock()  # cache structure + counters + weights
         self.hits = 0    # guarded-by: _lock
         self.misses = 0  # guarded-by: _lock
@@ -1022,6 +1145,7 @@ class InferenceEngine:
 
     def update_pipe(self, manifest=None, like_params=None) -> UpdatePipe:
         """The engine's (lazily created) trainer-update ingestion pipe."""
+        refuse_fleet(self.model, "the update pipe")
         with self._pipe_lock:
             pipe, created = self._pipe, False
             if pipe is None:
@@ -1113,27 +1237,28 @@ class InferenceEngine:
                 return entry[1], entry[2]
 
         def host_view(t):
-            if hasattr(t, "gather_np"):  # sharded-view table: already host
+            if t is None or hasattr(t, "gather_np"):  # none, or already host
                 return t
             if isinstance(t, dict):
                 return {k: np.asarray(v) for k, v in t.items()}
             return np.asarray(t)
 
-        emb = host_view(params["ffm"]["emb"])
-        lr = host_view(params["lr"]["w"])
+        emb, lr = (host_view(t) for t in self._head.tables(params))
         self._host_tables = ((params, emb, lr),) + self._host_tables[:1]
         return emb, lr
 
     _device_tables: Tuple = ()  # up to 2 of (params, device twin)
 
     def _device_params(self, params):
-        """``params`` with every numpy leaf copied to the device — the twin
-        the in-trace forward (:func:`batched_candidates_forward`) takes, so
-        the gather tables cross to the device once per published generation
-        instead of inside every forward call. Non-array leaves (the LR
-        table's Python-int ``block``) stay as they are. Returns ``params``
-        itself where :func:`_tables_resident` says no twin is kept (host
-        pre-gather, CPU backend, sharded views).
+        """``params`` in the head's serving form (``Head.serving_params``)
+        with every numpy leaf copied to the device — the twin the in-trace
+        forward (:func:`batched_candidates_forward`) takes, so the gather
+        tables cross to the device once per published generation instead
+        of inside every forward call. Non-array leaves (the LR table's
+        Python-int ``block``) stay as they are. Returns the serving form as
+        it is where :func:`_tables_resident` says no twin is kept (host
+        pre-gather, CPU backend, sharded views): for the FFM heads that is
+        ``params`` itself.
 
         Cached per params object in two slots, like :meth:`_host_weights`:
         the published generation and the one being published. Construction,
@@ -1144,17 +1269,22 @@ class InferenceEngine:
         for entry in self._device_tables:
             if entry[0] is params:
                 return entry[1]
-        if not _tables_resident(self.host_gather, params):
+        served = (params if params is None else
+                  self._head.serving_params(self.cfg, self.backend, params))
+        resident = _tables_resident(self.host_gather, served)
+        if not resident and served is params:
             return params
-        nbytes = host_arg_nbytes(params)
-        twin = jax.block_until_ready(jax.tree_util.tree_map(
-            lambda x: (jax.device_put(x)
-                       if isinstance(x, (np.ndarray, np.generic)) else x),
-            params))
+        twin = served
+        if resident:
+            nbytes = host_arg_nbytes(served)
+            twin = jax.block_until_ready(jax.tree_util.tree_map(
+                lambda x: (jax.device_put(x)
+                           if isinstance(x, (np.ndarray, np.generic)) else x),
+                served))
+            with self._lock:
+                self.stats.table_uploads += 1
+                self.stats.table_upload_bytes += nbytes
         self._device_tables = ((params, twin),) + self._device_tables[:1]
-        with self._lock:
-            self.stats.table_uploads += 1
-            self.stats.table_upload_bytes += nbytes
         return twin
 
     def _head_params(self, params):
@@ -1192,7 +1322,7 @@ class InferenceEngine:
                            if d < fc]
         states: List[Optional[Dict]] = [None] * len(ctxs)
         full_hit: List[bool] = [False] * len(ctxs)
-        emb_dt = ffm.table_dtype(params["ffm"]["emb"])
+        head = self._head
 
         pending = list(range(len(ctxs)))
         first_round = True
@@ -1222,22 +1352,22 @@ class InferenceEngine:
                     miss_groups.setdefault(depth, []).append(i)
             first_round = False
 
-            # tails are computed on host (ffm.extend_context_prefix_np): the
+            # tails are computed on host (the head's extend_state): the
             # arithmetic is tiny (members x tail fields x F x k), so the old
             # vmapped-jit path paid more in group stacking, padded buckets,
             # dispatch, and device->host result transfers than the math —
             # the PR 2 overlap-traffic regression. Host tails also never
             # compile, so prewarm/resolution cannot stall mid-traffic.
             emb_h, lr_h = self._host_weights(params)
-            empty = ffm.empty_context_prefix_np(self.cfg, emb_dt)
+            empty = head.empty_state(self.cfg, params)
             for depth, members in sorted(miss_groups.items()):
                 t = fc - depth
                 fresh = []
                 with _span(phases, "serve.tails"):
                     for i in members:
-                        base = (ffm.slice_context_prefix(looked[i][1], depth)
+                        base = (head.slice_state(looked[i][1], depth)
                                 if looked[i][1] is not None else empty)
-                        fresh.append(ffm.extend_context_prefix_np(
+                        fresh.append(head.extend_state(
                             self.cfg, emb_h, lr_h, base,
                             ctxs[i][1][depth:], ctxs[i][2][depth:]))
                 with self._lock:
@@ -1728,10 +1858,10 @@ class InferenceEngine:
         gather — the scoring pool's recycled double buffer. The in-trace
         forward takes the whole tables as arguments: their device twin
         (:meth:`_device_params`) where the engine keeps one."""
-        emb = params["ffm"]["emb"]
         if self.host_gather:
             from repro.kernels.row_gather import ops as rg_ops
 
+            emb = params["ffm"]["emb"]
             emb_h, lr_h = self._host_weights(params)
             lr_cand = (ffm.gather_lr_np(lr_h, ki_b)
                        * kv_b).sum(-1).astype(np.float32)
@@ -1765,7 +1895,7 @@ class InferenceEngine:
                     self.cfg, self.model, self.backend,
                     self._head_params(params), stacked,
                     ec.astype(np.float32, copy=False), kv_b, lr_cand)
-        return batched_candidates_forward, (
+        return self._head.forward, (
             self.cfg, self.model, self.backend, self._device_params(params),
             stacked, ki_b, kv_b)
 
@@ -1783,8 +1913,8 @@ class InferenceEngine:
         :meth:`lower_candidates_forward` lowers."""
         cfg = self.cfg
         fc, fcand = cfg.context_fields, cfg.n_fields - cfg.context_fields
-        emb_dt = ffm.table_dtype(self.params["ffm"]["emb"])
         if self.fused:
+            emb_dt = ffm.table_dtype(self.params["ffm"]["emb"])
             cached = {
                 "emb": np.zeros((rb, fc, cfg.n_fields, cfg.k), emb_dt),
                 "val": np.zeros((rb, fc), np.float32),
@@ -1793,12 +1923,7 @@ class InferenceEngine:
                 "lr_terms": np.zeros((rb, fc), np.float32),
             }
         else:
-            cached = {
-                "emb": np.zeros((rb, fc, cfg.n_fields, cfg.k), emb_dt),
-                "val": np.zeros((rb, fc), np.float32),
-                "pairs": np.zeros((rb, ffm.prefix_pair_count(fc)), np.float32),
-                "lr_terms": np.zeros((rb, fc), np.float32),
-            }
+            cached = self._head.dummy_state(cfg, self.params, rb)
         return (cached, np.zeros((rb, nb, fcand), np.int32),
                 np.zeros((rb, nb, fcand), np.float32))
 
@@ -1921,5 +2046,5 @@ class InferenceEngine:
             from repro.kernels.ffm_interaction import ops as ffm_ops
 
             interactions_fn = ffm_ops.interactions
-        return deepffm.forward(self.cfg, self.params, idx, val, self.model,
-                               interactions_fn=interactions_fn)
+        return self._head.oracle(self.cfg, self.params, idx, val, self.model,
+                                 interactions_fn)

@@ -5,7 +5,8 @@ tree, so two requests whose contexts agree on a leading run of fields share
 the cached work for that run. This module is the structured equivalent over
 hashed features: a trie whose edges are ``(idx, val)`` field tokens and whose
 nodes can hold a *prefix partial* — the FFM context state restricted to the
-fields along the path (``repro.core.ffm.extend_context_prefix`` format).
+fields along the path (``repro.core.ffm.extend_context_prefix`` format, or
+the ``dcnv2`` head's value-scaled context rows, ``repro.core.dcnv2``).
 
 A lookup walks the trie as deep as the request's tokens match and returns the
 deepest node holding a partial that is (a) stamped with the current weight
@@ -100,12 +101,16 @@ class PrefixCache:
     engine) inside the same structure. ``depths`` overrides ``stride`` with
     an explicit checkpoint-depth set (adaptive depths picked from an observed
     hit histogram — ``InferenceEngine.suggest_checkpoint_depths``); the full
-    depth is always included.
+    depth is always included. ``slice_state(state, depth)`` is the head's
+    prefix slice of a state (the FFM prefix state's by default; the serving
+    engine passes its head's), which eviction uses to truncate shared
+    nodes.
     """
 
     def __init__(self, fc: int, max_entries: int = 4096,
                  stride: Optional[int] = 4,
-                 depths: Optional[Sequence[int]] = None):
+                 depths: Optional[Sequence[int]] = None,
+                 slice_state=ffm.slice_context_prefix):
         if fc < 1:
             raise ValueError("need at least one context field")
         if stride is not None and stride < 1:
@@ -115,6 +120,7 @@ class PrefixCache:
             if depths[0] < 1 or depths[-1] > fc:
                 raise ValueError(f"checkpoint depths must lie in [1, {fc}]")
         self.fc = fc
+        self.slice_state = slice_state
         self.max_entries = max_entries
         self.stride = stride
         self.depths = depths
@@ -220,7 +226,7 @@ class PrefixCache:
                     if depth_g > d:
                         node.entries[gen] = (d, {
                             k: v.copy()
-                            for k, v in ffm.slice_context_prefix(s, d).items()})
+                            for k, v in self.slice_state(s, d).items()})
         # prune the unshared suffix of the path (radix-tree leaf drop)
         for parent, tok in reversed(path):
             child = parent.children[tok]

@@ -115,7 +115,7 @@ from repro.core import quantization as Q
 from repro.kernels.row_gather import ops as rg_ops
 from repro.launch.topology import ShardTopology
 from repro.serving.engine import (InferenceEngine, ScoringPool,
-                                  _finish_candidates)
+                                  _finish_candidates, refuse_fleet)
 from repro.serving.faults import FaultPlan
 
 
@@ -349,6 +349,7 @@ class ShardRouter(InferenceEngine):
                  replicas: int = 1, hedge_ms: Optional[float] = None,
                  probe_interval_s: float = 0.2,
                  faults: Optional[FaultPlan] = None):
+        refuse_fleet(model, "ShardRouter")
         self.topology = ShardTopology.build(cfg, model, n_shards,
                                             replicas=replicas)
         # ONE pool for the whole fleet: the router's scatter-gather fan-out

@@ -118,7 +118,7 @@ def test_deepffm_beats_linear_on_interaction_data():
 
 
 def test_dcnv2_trains():
-    cfg = CFG
+    cfg = CFG.replace(n_cross=3)
     stream = CTRStream(cfg, seed=8)
     params = dcnv2.init_params(cfg, jax.random.PRNGKey(0))
     vg = jax.jit(jax.value_and_grad(lambda p, b: dcnv2.loss_fn(cfg, p, b)))

@@ -111,3 +111,32 @@ def test_row_gather_q8_compiles(one_chip):
         lambda *a: gather_dequant_rows_q8(*a, interpret=False), one_chip,
         ((V, F, KK), i8), ((V,), f32), ((V,), f32), ((R, N, FCAND), i32))
     assert "tpu_custom_call" in txt
+
+
+def test_dcn_cross_q8_compiles(one_chip):
+    """The DCN-v2 cross kernel at the ``dcnv2_criteo`` widths (39 fields of
+    width 39, 16 of them context, three cross layers, deep 768-768): its
+    split weights fit the kernel's VMEM limit in one buffer each."""
+    from repro.kernels.dcn_cross import ops as dcn_ops
+
+    fc, fa, k, d = 16, 23, 39, 39 * 39
+
+    def s(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cross = {f"{n}{l}": s((d, d) if n == "w" else (d,))
+             for l in range(3) for n in "wb"}
+    dims = (d, 768, 768, 1)
+    mlp = {}
+    for i in range(3):
+        mlp[f"w{i}"], mlp[f"b{i}"] = s(dims[i:i + 2]), s((dims[i + 1],))
+    cands = (R, N, fa)
+    def call(x0c, p, codes, scale, zero, val, cross, mlp):
+        weights = dcn_ops.kernel_weights(cross, mlp, n_ctx=fc, n_cand=fa, k=k)
+        return dcn_ops.dcn_cross_q8(x0c, p, codes, scale, zero, val, weights,
+                                    interpret=False)
+
+    txt = jax.jit(call).lower(
+        s((R, fc * k)), s((R, d)), s(cands + (k,), i8), s(cands), s(cands),
+        s(cands), cross, mlp).compile().as_text()
+    assert "tpu_custom_call" in txt and "%dcn_cross_q8" in txt
